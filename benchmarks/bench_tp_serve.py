@@ -17,8 +17,10 @@ pool on a 2-way mesh.
 
 Needs >1 device, and XLA's forced host-device count must be set before
 jax imports — so the measured section self-spawns as a child process
-(``--child``) with ``--xla_force_host_platform_device_count=2``; the
-parent stays device-count agnostic and just gates the child's JSON.
+(``--child``) with ``--xla_force_host_platform_device_count=2`` and
+``JAX_PLATFORMS=cpu`` — a rehearsal on host devices that never contends
+for an accelerator the parent may hold; the parent stays device-count
+agnostic and just gates the child's JSON.
 
   smoke: GQA (Pallas paged attention) only, short trace — the CI gate.
   full:  GQA + MLA + GQA-with-speculation, longer trace; records tok/s
@@ -103,6 +105,7 @@ def _spawn(mode: str) -> dict:
     env["PYTHONPATH"] = str(REPO / "src") + os.pathsep + str(REPO)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=2")
+    env["JAX_PLATFORMS"] = "cpu"      # host devices; never the chip
     r = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_tp_serve", "--child", mode],
         capture_output=True, text=True, timeout=3600, env=env,

@@ -347,6 +347,8 @@ class Pilot:
             accepted = self.repo.complete(result)
             if result.exitcode != 0:
                 self.repo.release(task, failed=True)
+                # a failed result never lands in the repo: keep the why
+                record["payload_error"] = result.telemetry.get("error")
             record["exitcode"] = result.exitcode
             record["accepted"] = accepted
             record["monitor_actions"] = [a.kind for a in monitor.actions]

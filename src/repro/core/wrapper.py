@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+import traceback
 
 import jax
 import numpy as np
@@ -100,9 +101,9 @@ def run_wrapper(arena: SharedArena, proctable: ProcessTable, exe, spec: dict):
                 proctable.heartbeat(entry.pid, dt)
                 telemetry["steps"] = i + 1
                 telemetry["step_times"].append(dt)
-    except Exception as e:                               # noqa: BLE001
+    except Exception:                                    # noqa: BLE001
         exitcode = 1
-        telemetry["error"] = f"{type(e).__name__}: {e}"
+        telemetry["error"] = traceback.format_exc(limit=-8)
     telemetry["wall"] = time.monotonic() - t_start
     telemetry["step_times"] = telemetry["step_times"][-16:]
     proctable.mark_exited(entry.pid, exitcode)

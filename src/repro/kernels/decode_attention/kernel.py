@@ -36,22 +36,27 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc,
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
     b = pl.program_id(0)
-    t_pos = ti * block_t + jax.lax.broadcasted_iota(jnp.int32, (1, block_t), 1)
-    valid = (t_pos < len_ref[b])[0]                           # (block_t,)
+    base = ti * block_t
 
-    @pl.when(jnp.any(valid))
+    @pl.when(base < len_ref[b])
     def _compute():
         q = q_ref[0, 0]                                       # (G, Dh)
         k = k_ref[0, 0]                                       # (block_t, Dh)
+        # masks straight from 2-D iotas (a bool-vector reshape is refused
+        # by the TPU compiler)
+        vrow = base + jax.lax.broadcasted_iota(
+            jnp.int32, v_ref.shape[2:], 0) < len_ref[b]       # (block_t, Dh)
+        scol = base + jax.lax.broadcasted_iota(
+            jnp.int32, (q.shape[0], block_t), 1) < len_ref[b]  # (G, block_t)
         # zero invalid rows: when T % block_t != 0 the final block reads
         # out-of-bounds rows (NaN in interpret mode); their p weight is 0
         # but 0*NaN would still poison the p@v contraction.
-        v = jnp.where(valid[:, None], v_ref[0, 0], 0.0)
+        v = jnp.where(vrow, v_ref[0, 0], 0.0)
         s = jax.lax.dot_general(
             q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale       # (G, block_t)
-        s = jnp.where(valid[None], s, NEG_INF)
+        s = jnp.where(scol, s, NEG_INF)
         m_prev = m_sc[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])

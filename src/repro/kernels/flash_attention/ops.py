@@ -16,8 +16,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
+from repro.runtime.mesh import MODEL_AXIS
 
 
 def _on_tpu() -> bool:
@@ -56,3 +58,22 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_offset=0,
                                t_total=T)
     o = o[:, :, :, :S]
     return o.transpose(0, 3, 1, 2, 4).reshape(B, S, H, Dh)
+
+
+def flash_attention_tp(q, k, v, mesh, *, causal=True, window=None,
+                       interpret=None):
+    """Head-sharded :func:`flash_attention` under shard_map for the serve
+    mesh: q (B,S,H,Dh) sharded on H, k/v (B,T,K,Dh) on K, output on H.  A
+    Mosaic kernel cannot be partitioned automatically, so each shard runs
+    the same kernel on its contiguous head slice — query head h attends kv
+    head h // G, so a shard owns exactly the kv heads its queries read.
+    Requires :func:`repro.runtime.mesh.tp_heads`."""
+    if interpret is None:
+        interpret = not _on_tpu()
+    heads = P(None, None, MODEL_AXIS, None)
+    fn = jax.shard_map(
+        functools.partial(flash_attention, causal=causal, window=window,
+                          interpret=interpret),
+        mesh=mesh, in_specs=(heads, heads, heads), out_specs=heads,
+        check_vma=False)
+    return fn(q, k, v)

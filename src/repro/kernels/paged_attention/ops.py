@@ -8,15 +8,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:                                    # moved to jax.shard_map in new jax
-    from jax.experimental.shard_map import shard_map
-except ImportError:                     # pragma: no cover
-    from jax import shard_map
-
-from repro.kernels.paged_attention.kernel import (
-    paged_decode_attention_kernel, paged_verify_attention_kernel,
-)
-from repro.runtime.mesh import MODEL_AXIS, mesh_axis_size
+from repro.kernels.paged_attention.kernel import paged_attention_kernel
+from repro.runtime.mesh import MODEL_AXIS
 
 
 def _on_tpu() -> bool:
@@ -40,9 +33,11 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, cache_len, *,
     G = H // K
     if interpret is None:
         interpret = not _on_tpu()
-    qg = q.reshape(B, K, G, Dh)
-    o = paged_decode_attention_kernel(qg, k_pool, v_pool, block_tables,
-                                      cache_len, interpret=interpret)
+    # one query per row, at position cache_len - 1
+    q_off = jnp.asarray(cache_len, jnp.int32) - 1
+    o = paged_attention_kernel(q.reshape(B, K, G, Dh), k_pool, v_pool,
+                               block_tables, q_off, group=G,
+                               interpret=interpret)
     return o.reshape(B, H, Dh)
 
 
@@ -63,9 +58,12 @@ def paged_verify_attention(q, k_pool, v_pool, block_tables, q_off, *,
     G = H // K
     if interpret is None:
         interpret = not _on_tpu()
-    qg = q.reshape(B, S, K, G, Dh)
-    o = paged_verify_attention_kernel(qg, k_pool, v_pool, block_tables,
-                                      q_off, interpret=interpret)
+    # kernel rows r = s*G + g, grouped under their kv head
+    qg = q.reshape(B, S, K, G, Dh).transpose(0, 2, 1, 3, 4)
+    o = paged_attention_kernel(qg.reshape(B, K, S * G, Dh), k_pool, v_pool,
+                               block_tables, q_off, group=G,
+                               interpret=interpret)
+    o = o.reshape(B, K, S, G, Dh).transpose(0, 2, 1, 3, 4)
     return o.reshape(B, S, H, Dh)
 
 
@@ -80,16 +78,8 @@ def paged_verify_attention(q, k_pool, v_pool, block_tables, q_off, *,
 # shard s owns query heads [s*H/m, (s+1)*H/m) and exactly the kv heads
 # [s*K/m, (s+1)*K/m) they attend — no cross-shard communication, and every
 # per-head softmax is bitwise identical to the single-device kernel.
-# check_rep=False: pallas_call inside shard_map cannot prove replication.
-
-def tp_heads(mesh, num_kv_heads: int, num_heads: int) -> bool:
-    """True iff the kernel can be head-sharded on this mesh: the model axis
-    must divide the KV head count (whole kv-groups per shard)."""
-    if mesh is None:
-        return False
-    m = mesh_axis_size(mesh, MODEL_AXIS)
-    return m > 1 and num_kv_heads % m == 0 and num_heads % m == 0
-
+# check_vma=False: pallas_call inside shard_map cannot prove replication.
+# Eligibility is :func:`repro.runtime.mesh.tp_heads`.
 
 def _len_spec(x) -> P:
     return P() if jnp.ndim(x) == 0 else P(*([None] * jnp.ndim(x)))
@@ -99,17 +89,18 @@ def paged_decode_attention_tp(q, k_pool, v_pool, block_tables, cache_len,
                               mesh, *, interpret=None):
     """Head-sharded paged_decode_attention under shard_map.  Same contract;
     q (B,H,Dh) sharded on H, pools (nb,bs,K,Dh) sharded on K, output
-    (B,H,Dh) sharded on H.  Requires :func:`tp_heads`."""
+    (B,H,Dh) sharded on H.  Requires
+    :func:`repro.runtime.mesh.tp_heads`."""
     if interpret is None:
         interpret = not _on_tpu()
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(paged_decode_attention, interpret=interpret),
         mesh=mesh,
         in_specs=(P(None, MODEL_AXIS, None), P(None, None, MODEL_AXIS, None),
                   P(None, None, MODEL_AXIS, None), P(None, None),
                   _len_spec(cache_len)),
         out_specs=P(None, MODEL_AXIS, None),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k_pool, v_pool, block_tables, cache_len)
 
 
@@ -119,7 +110,7 @@ def paged_verify_attention_tp(q, k_pool, v_pool, block_tables, q_off,
     sharded on H; pools on K; output sharded on H."""
     if interpret is None:
         interpret = not _on_tpu()
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(paged_verify_attention, interpret=interpret),
         mesh=mesh,
         in_specs=(P(None, None, MODEL_AXIS, None),
@@ -127,5 +118,5 @@ def paged_verify_attention_tp(q, k_pool, v_pool, block_tables, q_off,
                   P(None, None, MODEL_AXIS, None), P(None, None),
                   _len_spec(q_off)),
         out_specs=P(None, None, MODEL_AXIS, None),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k_pool, v_pool, block_tables, q_off)

@@ -9,7 +9,7 @@ the GPU selective-scan to the TPU memory hierarchy.
 
 Per-chunk math (all f32 in VMEM):
     dA    = dt * A_h                       (Q,)
-    cum   = inclusive cumsum(dA)           (Q,)
+    cum   = inclusive cumsum(dA)           (Q,)  as a triangular matmul
     L     = exp(cum_q - cum_j) masked to j<=q
     y     = ((C B^T) . L) @ (dt * x)       intra-chunk, (Q,P)
           + exp(cum) * (C @ state)         inter-chunk carry-in
@@ -29,8 +29,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, s_final_ref,
-                state_sc, *, chunk, n_chunks):
+def _ssd_kernel(a_ref, x_ref, dtr_ref, dtc_ref, b_ref, c_ref, y_ref,
+                s_final_ref, state_sc, *, chunk, n_chunks):
     h = pl.program_id(1)
     ci = pl.program_id(2)
 
@@ -40,37 +40,49 @@ def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, s_final_ref,
 
     A = a_ref[h]                                              # scalar
     x = x_ref[0, 0].astype(jnp.float32)                       # (Q,P)
-    dt = dt_ref[0, 0].astype(jnp.float32)                     # (Q,)
+    dt_r = dtr_ref[0, 0].astype(jnp.float32)                  # (1,Q)
+    dt_c = dtc_ref[0, 0].astype(jnp.float32)                  # (Q,1)
     B = b_ref[0, 0].astype(jnp.float32)                       # (Q,N)
     C = c_ref[0, 0].astype(jnp.float32)                       # (Q,N)
 
-    dA = dt * A                                               # (Q,) <= 0
-    cum = jnp.cumsum(dA)                                      # (Q,)
-    total = cum[-1]
-
-    # ---- intra-chunk (Q,Q) masked decay matmul
-    seg = cum[:, None] - cum[None, :]
+    # inclusive cumsum of dA as a lower-triangular matmul, in row and
+    # column form (the TPU lowering has no cumsum and no vector transpose
+    # of a 1-D value)
     qi = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     ji = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    seg = jnp.where(qi >= ji, seg, -jnp.inf)
+    causal = qi >= ji
+    tri = causal.astype(jnp.float32)                          # (Q,Q)
+    hi = jax.lax.Precision.HIGHEST
+    dA_r = dt_r * A                                           # (1,Q) <= 0
+    dA_c = dt_c * A                                           # (Q,1)
+    cum_c = jax.lax.dot_general(tri, dA_c, (((1,), (0,)), ((), ())),
+                                precision=hi,
+                                preferred_element_type=jnp.float32)
+    cum_r = jax.lax.dot_general(dA_r, tri, (((1,), (1,)), ((), ())),
+                                precision=hi,
+                                preferred_element_type=jnp.float32)
+    total = jnp.sum(dA_r, axis=1, keepdims=True)              # (1,1)
+
+    # ---- intra-chunk (Q,Q) masked decay matmul
+    seg = jnp.where(causal, cum_c - cum_r, -jnp.inf)
     L = jnp.exp(seg)                                          # (Q,Q)
     CB = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    xdt = x * dt[:, None]                                     # (Q,P)
+    xdt = x * dt_c                                            # (Q,P)
     y = jax.lax.dot_general(CB * L, xdt, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
     # ---- inter-chunk carry-in
     state = state_sc[...]                                     # (N,P)
-    y += jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(cum_c) * jax.lax.dot_general(
         C, state, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # ---- state update
-    decay_out = jnp.exp(total - cum)                          # (Q,)
+    decay_out = jnp.exp(total - cum_c)                        # (Q,1)
     S_loc = jax.lax.dot_general(
-        B, x * (dt * decay_out)[:, None],
+        B, x * (dt_c * decay_out),
         (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                   # (N,P)
     state_sc[...] = jnp.exp(total) * state + S_loc
@@ -95,7 +107,11 @@ def ssd_scan_kernel(x, dt, A, B, C, *, chunk=128, interpret=False):
         grid=(b, H, n_chunks),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda bi, h, ci, a: (bi, h, ci, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda bi, h, ci, a: (bi, h, ci)),
+            # dt twice: as a row (b,H,1,S) and as a column (b,H,S,1)
+            pl.BlockSpec((1, 1, 1, chunk),
+                         lambda bi, h, ci, a: (bi, h, 0, ci)),
+            pl.BlockSpec((1, 1, chunk, 1),
+                         lambda bi, h, ci, a: (bi, h, ci, 0)),
             pl.BlockSpec((1, 1, chunk, N),
                          lambda bi, h, ci, a: (bi, h // rep, ci, 0)),
             pl.BlockSpec((1, 1, chunk, N),
@@ -115,4 +131,4 @@ def ssd_scan_kernel(x, dt, A, B, C, *, chunk=128, interpret=False):
             jax.ShapeDtypeStruct((b, H, N, P), jnp.float32),
         ],
         interpret=interpret,
-    )(A.astype(jnp.float32), x, dt, B, C)
+    )(A.astype(jnp.float32), x, dt[:, :, None, :], dt[..., None], B, C)

@@ -10,7 +10,8 @@ production mesh and extract the roofline terms from the compiled artifact.
   python -m repro.launch.dryrun --all [--multi-pod] [--skip-existing]
 
 Single-cell mode runs in-process; ``--all`` spawns one subprocess per cell
-(fresh XLA state, bounded memory) and aggregates JSON records under
+(fresh XLA state, bounded memory, ``JAX_PLATFORMS=cpu`` so a child never
+contends for an accelerator) and aggregates JSON records under
 ``results/dryrun/<mesh>/``.  The 512 placeholder host devices exist ONLY in
 this entrypoint — nothing else in the repo sets XLA_FLAGS.
 """
@@ -280,8 +281,11 @@ def run_all(multi_pod: bool, skip_existing: bool, timeout: float = 3000.0):
             cmd.append("--multi-pod")
         t0 = time.monotonic()
         try:
+            # the child compiles on placeholder host devices: pinned to
+            # the CPU, it never contends for an accelerator
             r = subprocess.run(cmd, capture_output=True, text=True,
-                               timeout=timeout)
+                               timeout=timeout,
+                               env={**os.environ, "JAX_PLATFORMS": "cpu"})
             ok = r.returncode == 0 and out.exists()
         except subprocess.TimeoutExpired:
             r, ok = None, False

@@ -147,8 +147,6 @@ def cost_summary(compiled) -> dict:
         ca = compiled.cost_analysis()
     except Exception:       # noqa: BLE001
         return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     out = {}
     for k in ("flops", "bytes accessed", "transcendentals", "optimal_seconds"):
         if k in ca:

@@ -14,15 +14,16 @@ from __future__ import annotations
 
 import jax
 
-from repro.runtime.mesh import DATA_AXIS, MODEL_AXIS, POD_AXIS
+from repro.runtime.mesh import DATA_AXIS, MODEL_AXIS, POD_AXIS, auto_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = (POD_AXIS, DATA_AXIS, MODEL_AXIS) if multi_pod else (DATA_AXIS, MODEL_AXIS)
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=auto_axes(len(axes)))
 
 
 def make_smoke_mesh(*, data: int = 1, model: int = 1):
     """Tiny mesh over however many devices the test environment has."""
-    return jax.make_mesh((data, model), (DATA_AXIS, MODEL_AXIS))
+    return jax.make_mesh((data, model), (DATA_AXIS, MODEL_AXIS),
+                         axis_types=auto_axes(2))

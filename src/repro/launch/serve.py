@@ -5,12 +5,20 @@
       [--pilots N [--fail-at K]]
 
 Default runs the continuous-batching engine directly on a staggered-arrival
-trace (``--wave`` selects the static wave-batching baseline for comparison);
-``--via-pilots`` submits full inference servers as ``serve`` payloads: each
+trace, at the model's published widths unless ``--smoke`` (``--wave``
+selects the static wave-batching baseline for comparison).
+``--via-pilots`` submits inference servers as ``serve`` payloads: each
 engine run — trace and all — is late-bound onto a pilot-held slice, and a
 second model is served by the SAME pilot right after (the multi-payload
 demo).  The first task carries a prefetch hint for the second image, so its
-compile overlaps the first server's run.
+compile overlaps the first server's run.  It exits non-zero unless the
+repo drains and every payload exits 0.
+
+Every pilot mode here (``--via-pilots``, ``--pilots``, ``--disagg``,
+``--autoscale``) builds SMOKE images: ``PayloadImage(shape="smoke")`` with
+the reduced config, CPU-sized.  ``chip_smoke.py`` at the repo root drives
+the same pilot path at published widths (``smoke=False``,
+``shape="custom:<seq>x<batch>"``, ``flags=(("attn_impl", "pallas"),)``).
 
 ``--pilots N`` runs the FLEET serve demo: the trace is split into
 per-request leases in a FleetDispatcher pool and N pilots each run a server
@@ -29,6 +37,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import sys
 import time
 
 import jax
@@ -38,6 +47,7 @@ from repro.configs.base import get_config, get_smoke_config
 from repro.core.cluster import ClusterSim
 from repro.core.images import PayloadImage
 from repro.core.pilot import PilotConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
 from repro.serving.dispatch import FleetDispatcher
 from repro.serving.engine import ServeEngine
@@ -90,10 +100,13 @@ def serve_direct(cfg, n_requests: int, slots: int, max_len: int,
 
 def serve_via_pilots(archs: list[str], n_requests: int = 8,
                      n_steps: int = 400, slots: int | None = None,
-                     max_len: int | None = None) -> None:
+                     max_len: int | None = None) -> int:
     """Several inference servers (different models!) multiplexed over ONE
     pilot — container late-binding for serving.  Task i hints task i+1's
-    image so the pilot prefetches the next compile during the current run."""
+    image so the pilot prefetches the next compile during the current run.
+
+    Returns the process exit code: 0 only if the repo drained and every
+    payload exited 0."""
     sim = ClusterSim()
     images = [PayloadImage(arch=a, shape="smoke", mode="serve") for a in archs]
     tids = []
@@ -117,6 +130,7 @@ def serve_via_pilots(archs: list[str], n_requests: int = 8,
     sim.join_all(timeout=30.0)
     print(f"[serve] drained={ok} repo={sim.repo.stats()} "
           f"registry={sim.registry.stats}")
+    exitcodes = [rec.get("exitcode") for rec in pilot.history]
     for i, (tid, arch) in enumerate(zip(tids, archs)):
         r = sim.repo.result(tid)
         if r:
@@ -126,6 +140,11 @@ def serve_via_pilots(archs: list[str], n_requests: int = 8,
                   f"tok/s={sv.get('tok_per_s', 0):.1f} "
                   f"ttft_p50={sv.get('ttft_p50_s')} "
                   f"(bind cached={pilot.history[i].get('bind_cached')})")
+        else:
+            print(f"  {arch}: no result (payload exit codes {exitcodes})")
+    failed = (not ok or any(sim.repo.result(t) is None for t in tids)
+              or any(c != 0 for c in exitcodes))
+    return 1 if failed else 0
 
 
 def serve_fleet(arch: str, n_requests: int, n_pilots: int, *,
@@ -677,6 +696,7 @@ def main():
                          "the demand-driven autoscaler (--pilots caps the "
                          "fleet; starts at 1, scales to zero in the gaps)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh_shape = None
     if args.mesh:
@@ -749,9 +769,8 @@ def main():
         return
     if args.via_pilots:
         archs = (args.archs or f"{args.arch},gemma-2b").split(",")
-        serve_via_pilots(archs, n_requests=args.requests, slots=args.slots,
-                         max_len=args.max_len)
-        return
+        sys.exit(serve_via_pilots(archs, n_requests=args.requests,
+                                  slots=args.slots, max_len=args.max_len))
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     draft_cfg = None
     if args.draft and args.draft != "self":

@@ -27,6 +27,7 @@ from repro.core.images import PayloadImage
 from repro.core.pilot import PilotConfig
 from repro.core.taskrepo import TaskRepo
 from repro.data.synthetic import SyntheticConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import init_train_state, make_train_step
 from repro.optim.adamw import OptimConfig
 
@@ -101,6 +102,7 @@ def main():
                     help="seconds until a simulated node failure")
     ap.add_argument("--pilots", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.direct:
         cfg = (get_smoke_config(args.arch) if args.smoke

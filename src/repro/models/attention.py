@@ -216,6 +216,12 @@ def attend(q, k, v, cfg, *, causal=True, window=None, q_offset=0):
                                         chunk=cfg.attn_chunk)
     if cfg.attn_impl == "pallas":
         from repro.kernels.flash_attention import ops as fa_ops
+        from repro.runtime.mesh import tp_heads
+        from repro.runtime.sharding import active_serve_mesh
+        mesh = active_serve_mesh()
+        if tp_heads(mesh, k.shape[2], q.shape[2]):
+            return fa_ops.flash_attention_tp(q, k, v, mesh, causal=causal,
+                                             window=window)
         return fa_ops.flash_attention(q, k, v, causal=causal, window=window)
     return chunked_attention(q, k, v, causal=causal, window=window,
                              q_offset=q_offset, chunk=cfg.attn_chunk)
@@ -432,9 +438,10 @@ def attention_decode(x, p, cfg, cache, pos, *, rope_theta=None,
         cache_len = jnp.minimum(pos + 1, T)
         if cfg.attn_impl == "pallas":
             from repro.kernels.paged_attention.ops import (
-                paged_decode_attention, paged_decode_attention_tp, tp_heads)
-            from repro.runtime.sharding import active_mesh
-            mesh = active_mesh()
+                paged_decode_attention, paged_decode_attention_tp)
+            from repro.runtime.mesh import tp_heads
+            from repro.runtime.sharding import active_serve_mesh
+            mesh = active_serve_mesh()
             if tp_heads(mesh, cfg.num_kv_heads, cfg.num_heads):
                 out = paged_decode_attention_tp(q[:, 0], k_pool, v_pool,
                                                 block_tables, cache_len,
@@ -516,9 +523,10 @@ def attention_verify(x, p, cfg, cache, pos, *, block_tables,
     T = block_tables.shape[1] * k_pool.shape[1]
     if cfg.attn_impl == "pallas":
         from repro.kernels.paged_attention.ops import (
-            paged_verify_attention, paged_verify_attention_tp, tp_heads)
-        from repro.runtime.sharding import active_mesh
-        mesh = active_mesh()
+            paged_verify_attention, paged_verify_attention_tp)
+        from repro.runtime.mesh import tp_heads
+        from repro.runtime.sharding import active_serve_mesh
+        mesh = active_serve_mesh()
         if tp_heads(mesh, cfg.num_kv_heads, cfg.num_heads):
             out = paged_verify_attention_tp(q, k_pool, v_pool, block_tables,
                                             pos, mesh)

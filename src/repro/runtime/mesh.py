@@ -13,7 +13,7 @@ import math
 from typing import Sequence
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 # Canonical physical axis names, outermost first.  "pod" is the slowest /
 # cross-ICI axis, "data" is the pure-replication/batch axis, "model" is the
@@ -52,12 +52,19 @@ class MeshSpec:
         return self.shape[self.axes.index(name)]
 
     def build(self, devices: Sequence[jax.Device] | None = None) -> Mesh:
+        """Auto axes: sharding follows the constraints the model code sets
+        (``with_sharding_constraint`` refuses Explicit axes)."""
         if devices is None:
-            return jax.make_mesh(self.shape, self.axes)
+            return jax.make_mesh(self.shape, self.axes,
+                                 axis_types=auto_axes(len(self.axes)))
         import numpy as np
 
         devs = np.asarray(devices).reshape(self.shape)
-        return Mesh(devs, self.axes)
+        return Mesh(devs, self.axes, axis_types=auto_axes(len(self.axes)))
+
+
+def auto_axes(n: int) -> tuple[AxisType, ...]:
+    return (AxisType.Auto,) * n
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
@@ -109,6 +116,15 @@ def mesh_axis_size(mesh: Mesh, name: str) -> int:
     return mesh.shape.get(name, 1) if hasattr(mesh.shape, "get") else dict(
         zip(mesh.axis_names, mesh.devices.shape)
     ).get(name, 1)
+
+
+def tp_heads(mesh: Mesh | None, num_kv_heads: int, num_heads: int) -> bool:
+    """True iff attention kernels can be head-sharded on this mesh: the
+    model axis must divide the KV head count (whole kv-groups per shard)."""
+    if mesh is None:
+        return False
+    m = mesh_axis_size(mesh, MODEL_AXIS)
+    return m > 1 and num_kv_heads % m == 0 and num_heads % m == 0
 
 
 def batch_axes(mesh: Mesh) -> tuple[str, ...]:

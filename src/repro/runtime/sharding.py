@@ -421,13 +421,13 @@ def activation_sharding(mesh: Mesh, layout: str = "2d"):
         _ACT.ctx = prev
 
 
-def active_mesh() -> Mesh | None:
-    """The mesh of the enclosing :func:`activation_sharding` context, or
-    None.  Read at TRACE time — model code uses it to pick sharded kernel
-    dispatch (shard_map over the head axis) without carrying a mesh through
-    every call signature."""
+def active_serve_mesh() -> Mesh | None:
+    """The mesh of the enclosing "serve" :func:`activation_sharding`
+    context, or None.  Read at TRACE time — model code uses it to pick
+    head-sharded kernel dispatch (shard_map over the head axis) without
+    carrying a mesh through every call signature."""
     ctx = getattr(_ACT, "ctx", None)
-    return ctx[0] if ctx is not None else None
+    return ctx[0] if ctx is not None and ctx[1] == "serve" else None
 
 
 def constrain_replicated(x):

@@ -57,16 +57,32 @@ def test_constrain_noop_without_context():
 
 
 def test_constrain_applies_in_context():
+    from repro.runtime.mesh import make_mesh
     from repro.runtime.sharding import activation_sharding, constrain
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     with activation_sharding(mesh, "2d"):
         out = jax.jit(lambda x: constrain(x, "b."))(jnp.zeros((4, 8)))
     assert out.shape == (4, 8)
 
 
+def test_serve_mesh_axes_are_auto():
+    """The serve mesh must carry Auto axes: ``with_sharding_constraint``
+    (behind constrain_replicated) refuses Explicit ones."""
+    from jax.sharding import AxisType
+    from repro.runtime.mesh import serve_mesh
+    from repro.runtime.sharding import (activation_sharding,
+                                        constrain_replicated)
+    mesh = serve_mesh((1, 1))
+    assert tuple(mesh.axis_types) == (AxisType.Auto, AxisType.Auto)
+    with activation_sharding(mesh, "serve"):
+        out = jax.jit(constrain_replicated)(jnp.ones((2, 3, 4)))
+    np.testing.assert_array_equal(np.asarray(out), np.ones((2, 3, 4)))
+
+
 def test_constrain_conflicting_axes_skipped():
+    from repro.runtime.mesh import make_mesh
     from repro.runtime.sharding import activation_sharding, constrain
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     x = jnp.zeros((4, 4))
     with activation_sharding(mesh, "2d"):
         # batch and expert dims both want "data" -> constraint skipped
