@@ -317,14 +317,6 @@ def run(arch: str = "smollm-360m", n_requests: int = 32,
          "live tokens / ALLOCATED tokens, mean over steps"),
         ("serve_kv_mem_util_dense", dense["kv_memory_utilization"],
          "live tokens / allocated tokens (slab allocates everything)"),
-        ("serve_chunked_itl_p99_s", chunked["itl_p99_s"],
-         "p99 per-token stall, chunked prefill, long-prompt mix"),
-        ("serve_oneshot_itl_p99_s", oneshot["itl_p99_s"],
-         "p99 per-token stall, stop-the-world prefill, long-prompt mix"),
-        ("serve_chunked_itl_p99_gain",
-         oneshot["itl_p99_s"] / chunked["itl_p99_s"]
-         if chunked["itl_p99_s"] else float("inf"),
-         "oneshot p99 stall / chunked p99 stall (>1 = stalls removed)"),
         ("serve_chunked_tpot_p99_s", chunked["tpot_p99_s"],
          "chunked prefill, long-prompt mix"),
         ("serve_oneshot_tpot_p99_s", oneshot["tpot_p99_s"],

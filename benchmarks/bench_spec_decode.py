@@ -265,8 +265,6 @@ def run(arch: str = "smollm-360m", slots: int = 4,
          if dist["decode_steps"] else 0.0,
          "device->host transfers per step (must be 1; k+1 tokens ride "
          "it)"),
-        ("spec_draft_overhead_s", dist["draft_overhead_s"],
-         "wall time inside the draft scan"),
         ("spec_distill_train_s", dtrain_s,
          f"{DISTILL_STEPS} teacher-forced steps, one-time cost"),
         ("spec_token_match", 1.0,

@@ -25,6 +25,8 @@ import threading
 import time
 from typing import Any
 
+from jax.profiler import TraceAnnotation
+
 from repro.analysis.locks import audit_callback, make_condition, make_lock
 from repro.core.arena import SharedArena
 from repro.core.images import Executable, ExecutableRegistry, PLACEHOLDER, PayloadImage
@@ -75,18 +77,20 @@ class PayloadExecutor:
     # the unprivileged pod patch
     # ------------------------------------------------------------------
 
-    def patch_image(self, cap: PodPatchCapability, image: PayloadImage):
+    def patch_image(self, cap: PodPatchCapability, image: PayloadImage,
+                    task_id: int | None = None):
         if cap.pod_id != self.pod_id:
             raise PermissionError_(
                 f"capability for pod {cap.pod_id!r} cannot patch {self.pod_id!r}")
-        t0 = time.monotonic()
-        exe = self.registry.pull(image, self.mesh)      # the image pull
-        with self._lock:
-            self.image = image
-            self.exe = exe
-            self.state = BOUND
-            self.generation += 1
-        self.last_bind_seconds = time.monotonic() - t0
+        with TraceAnnotation("repro.latebind.pull", task_id=task_id):
+            t0 = time.monotonic()
+            exe = self.registry.pull(image, self.mesh)  # the image pull
+            with self._lock:
+                self.image = image
+                self.exe = exe
+                self.state = BOUND
+                self.generation += 1
+            self.last_bind_seconds = time.monotonic() - t0
         self.last_bind_cached = exe.cached
         return exe
 

@@ -39,10 +39,13 @@ the system's node-failure story.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
 import uuid
+
+from jax.profiler import TraceAnnotation
 
 from repro.core.arena import SharedArena
 from repro.core import chaos
@@ -269,26 +272,30 @@ class Pilot:
             self.proctable,
             MonitorLimits(max_wall=task.max_wall),
             fleet_median_fn=self.repo.fleet_median_step_time)
+        # the finish span opens at (e) and closes after (f)'s cleanup
+        finish = contextlib.ExitStack()
         try:
             # (c) late bind: image patch + staging + startup spec
-            exe = self.executor.patch_image(self._cap, task.image)
-            for name, data in task.input_files.items():
-                self.arena.stage_file(name, data)
-            self._transition("bound")
-            self._wake.clear()
-            self.executor.start(spec_timeout=self.config.spec_timeout,
-                                on_exit=self._wake.set)
-            # env rides in the startup spec (the paper's startup script
-            # carries the env exports): one shared-volume publish, not two;
-            # payload_spec carries payload-kind extras (a serve payload's
-            # request trace and engine geometry)
-            self.arena.publish_startup_spec({
-                "n_steps": task.n_steps,
-                "task_id": task.task_id,
-                "env": {**task.env, "pilot": self.pilot_id},
-                **task.resume,
-                **task.payload_spec,
-            })
+            exe = self.executor.patch_image(self._cap, task.image,
+                                            task_id=task.task_id)
+            with TraceAnnotation("repro.pilot.stage", task_id=task.task_id):
+                for name, data in task.input_files.items():
+                    self.arena.stage_file(name, data)
+                self._transition("bound")
+                self._wake.clear()
+                self.executor.start(spec_timeout=self.config.spec_timeout,
+                                    on_exit=self._wake.set)
+                # env rides in the startup spec (the paper's startup script
+                # carries the env exports): one shared-volume publish, not
+                # two; payload_spec carries payload-kind extras (a serve
+                # payload's request trace and engine geometry)
+                self.arena.publish_startup_spec({
+                    "n_steps": task.n_steps,
+                    "task_id": task.task_id,
+                    "env": {**task.env, "pilot": self.pilot_id},
+                    **task.resume,
+                    **task.payload_spec,
+                })
             record["bind_seconds"] = self.executor.last_bind_seconds
             record["bind_cached"] = self.executor.last_bind_cached
             self._transition("running")
@@ -336,6 +343,8 @@ class Pilot:
 
             # (e) collect exit + outputs — fires the instant the exit event
             # is published, not at the next monitor tick
+            finish.enter_context(TraceAnnotation("repro.pilot.finish",
+                                                 task_id=task.task_id))
             self._transition("collecting")
             exit_info = self.arena.read_exit() or {"exitcode": 125,
                                                    "telemetry": {}}
@@ -362,18 +371,20 @@ class Pilot:
             # would keep a vanished pilot's lease alive forever
             for t in timers:
                 t.cancel()
-            if self.fail_flag.is_set():
-                pass          # hard node loss: no cleanup at all (paper §4);
+            with finish:
+                if self.fail_flag.is_set():
+                    pass      # hard node loss: no cleanup at all (paper §4);
                               # the lease expires and the task re-queues
-            else:
-                # (f) cleanup: container restart + volume wipe + orphan sweep
-                if self.executor is not None:
-                    self.executor.reset(back_to_placeholder=False)
-                self.arena.wipe_shared()
-                self.payloads_run += 1
-                self.history.append(record)
-                if self.state != "idle":
-                    self._transition("idle")
+                else:
+                    # (f) cleanup: container restart + volume wipe + orphan
+                    # sweep
+                    if self.executor is not None:
+                        self.executor.reset(back_to_placeholder=False)
+                    self.arena.wipe_shared()
+                    self.payloads_run += 1
+                    self.history.append(record)
+                    if self.state != "idle":
+                        self._transition("idle")
 
     # ---- (h) ----------------------------------------------------------
 

@@ -41,6 +41,8 @@ import time
 from collections import deque
 from typing import Any, Callable
 
+from jax.profiler import TraceAnnotation
+
 from repro.analysis.locks import (
     RANK_REPO,
     audit_callback,
@@ -272,7 +274,8 @@ class TaskRepo:
     def submit(self, image, **kw) -> int:
         with self._lock:
             tid = next(self._ids)
-            self._enqueue(PayloadTask(task_id=tid, image=image, **kw))
+            with TraceAnnotation("repro.repo.submit", task_id=tid):
+                self._enqueue(PayloadTask(task_id=tid, image=image, **kw))
             return tid
 
     # ---- matchmaking (step (b)) ---------------------------------------------

@@ -23,6 +23,7 @@ import traceback
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.arena import SharedArena
 from repro.core.proctable import PAYLOAD_UID, ProcessTable
@@ -133,14 +134,17 @@ def _serve_loop(exe, key, n_steps, entry, proctable, telemetry, spec) -> int:
     the pilot's monitor meters serve progress exactly as it meters train
     steps.
     """
-    params = exe.make_inputs(key)
+    task_id = spec.get("task_id")
+    with TraceAnnotation("repro.payload.weights", task_id=task_id):
+        params = exe.make_inputs(key)
     kv_kw = {k: spec[k] for k in ("kv", "prefill", "prefill_chunk",
                                   "num_blocks", "block_size",
                                   "prefix_sharing", "spec", "spec_k",
                                   "mesh_shape", "role")
              if spec.get(k) is not None}
-    eng = exe.fn(params, slots=spec.get("slots"),
-                 max_len=spec.get("max_len"), **kv_kw)
+    with TraceAnnotation("repro.payload.engine_build", task_id=task_id):
+        eng = exe.fn(params, slots=spec.get("slots"),
+                     max_len=spec.get("max_len"), **kv_kw)
     if spec.get("dispatch"):
         return _fleet_serve_loop(eng, spec, n_steps, entry, proctable,
                                  telemetry)
@@ -175,7 +179,6 @@ _SERVE_STAT_KEYS = (
     "kv_capacity_tokens", "prefix_hit_rate", "prefill_chunks",
     "blocked_admissions",
     "spec", "spec_fallback_reason", "acceptance_rate", "tokens_per_step",
-    "draft_overhead_s",
     "mesh_shape", "mesh_devices", "slots",
     "kv_pool_bytes", "kv_pool_bytes_per_device",
     "role", "prefills_exported", "handoffs_imported")

@@ -793,8 +793,7 @@ def main():
     if args.draft:
         print(f"[spec] spec={stats['spec']} "
               f"acceptance_rate={stats['acceptance_rate']:.3f} "
-              f"tokens_per_step={stats['tokens_per_step']:.2f} "
-              f"draft_overhead_s={stats['draft_overhead_s']:.3f}")
+              f"tokens_per_step={stats['tokens_per_step']:.2f}")
     print(json.dumps(stats, indent=1))
 
 

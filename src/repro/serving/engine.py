@@ -78,6 +78,7 @@ from collections import deque
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.api import build_model, init_decode_state
 from repro.serving.blockpool import BlockAllocator, KVHandoff, PrefixCache
@@ -502,7 +503,6 @@ class ServeEngine:
         # host mirrors (paged bookkeeping + cache-pressure stats)
         self._host_pos = [0] * slots
         self._slot_blocks: list[list[int]] = [[] for _ in range(slots)]
-        self._tick_times: list[float] = []     # wall time of decode ticks
         self.steps = 0
         self.idle_slot_steps = 0       # slots with no request during a step
         self.d2h_transfers = 0         # must equal `steps` (one per step)
@@ -518,7 +518,6 @@ class ServeEngine:
         self.spec_drafted = 0          # draft proposals scored by verify
         self.spec_accepted = 0         # of those, committed to requests
         self.tokens_emitted = 0        # total committed tokens (all modes)
-        self.draft_time_s = 0.0        # wall time inside the draft chain
 
         # one compiled decode step for the whole engine lifetime; engine
         # state (decode state + budget + active) is donated every step.
@@ -745,20 +744,21 @@ class ServeEngine:
                 off=nhit * bs, row=row, keys=keys, nhit=nhit))
             return True
 
-        logits, cache = self._prefill(
-            self.params, {"tokens": jnp.asarray(padded[None])})
-        nxt = int(jnp.argmax(logits[0, -1]))                  # admission-time
-        if self.kv == "paged":
-            self.state = _install_slot_paged(
-                self.state, cache, si, plen, nxt, row, nhit, bs)
-            self._publish_prefix(keys, row, nhit, shareable)
-            self._install_draft(padded, row, nhit)
-        else:
-            self.state = _install_slot(self.state, cache, si, plen, nxt)
-        if self.role == "prefill":
-            self._finish_prefill_export(si, req, plen, nxt, padded, keys)
-        else:
-            self._finish_admission(si, req, plen, nxt)
+        with TraceAnnotation("repro.engine.admit", rid=req.rid):
+            logits, cache = self._prefill(
+                self.params, {"tokens": jnp.asarray(padded[None])})
+            nxt = int(jnp.argmax(logits[0, -1]))              # admission-time
+            if self.kv == "paged":
+                self.state = _install_slot_paged(
+                    self.state, cache, si, plen, nxt, row, nhit, bs)
+                self._publish_prefix(keys, row, nhit, shareable)
+                self._install_draft(padded, row, nhit)
+            else:
+                self.state = _install_slot(self.state, cache, si, plen, nxt)
+            if self.role == "prefill":
+                self._finish_prefill_export(si, req, plen, nxt, padded, keys)
+            else:
+                self._finish_admission(si, req, plen, nxt)
         return True
 
     def _finish_admission(self, si: int, req: Request, plen: int, nxt: int):
@@ -1109,7 +1109,6 @@ class ServeEngine:
         prefill chunk, then one batched decode step.  Returns the number of
         live slots decoded (0 when no slot is decoding — an idle or
         admission-only tick is not a decode step)."""
-        t_tick = time.monotonic()
         self._admit()
         self._prefill_tick()
         actives = [si for si, m in enumerate(self.slot_meta) if m.active]
@@ -1117,28 +1116,33 @@ class ServeEngine:
             return 0
         self._ensure_sharded()
         guard = self._guard_rows() if self._jobs else None
+        step = self.steps
         if self.spec == "draft":
             # draft chain: k small-model decodes in one dispatch, writing
-            # into the shadow pools.  block_until_ready is a host SYNC, not
-            # a transfer — the drafts stay device-resident and feed verify
-            # directly; only the packed verify result crosses to the host.
-            t_draft = time.monotonic()
-            drafts, self._draft_cache = self._draft_fn(
-                self.draft_params, self._draft_cache, self.state["token"],
-                self.state["pos"], self.state["block_tables"])
-            jax.block_until_ready(drafts)
-            self.draft_time_s += time.monotonic() - t_draft
-            packed, self.state, self.active, self.budget = self._verify_fn(
-                self.params, self.state, self.active, self.budget, drafts)
-        else:
-            packed, self.state, self.active, self.budget = self._step_fn(
-                self.params, self.state, self.active, self.budget)
-        if guard is not None:
-            self._restore_rows(guard)
+            # into the shadow pools.  The drafts stay device-resident and
+            # feed verify directly; only the packed verify result crosses
+            # to the host.
+            with TraceAnnotation("repro.engine.draft", step=step):
+                drafts, self._draft_cache = self._draft_fn(
+                    self.draft_params, self._draft_cache,
+                    self.state["token"], self.state["pos"],
+                    self.state["block_tables"])
+        with TraceAnnotation("repro.engine.decode", step=step):
+            if self.spec == "draft":
+                packed, self.state, self.active, self.budget = \
+                    self._verify_fn(self.params, self.state, self.active,
+                                    self.budget, drafts)
+            else:
+                packed, self.state, self.active, self.budget = \
+                    self._step_fn(self.params, self.state, self.active,
+                                  self.budget)
+            if guard is not None:
+                self._restore_rows(guard)
         self.steps += 1
         self.idle_slot_steps += self.slots - len(actives)
-        # lint: allow[one-transfer] -- THE single whitelisted device→host transfer per step (d2h_transfers counts it)
-        out = jax.device_get(packed)
+        with TraceAnnotation("repro.engine.readback", step=step):
+            # lint: allow[one-transfer] -- THE single whitelisted device→host transfer per step (d2h_transfers counts it)
+            out = jax.device_get(packed)
         self.d2h_transfers += 1
         if self.spec == "draft":
             acc, dones, tok_rows = out[0], out[1], out[2:]
@@ -1168,10 +1172,6 @@ class ServeEngine:
             # the target ratified; the last is the target's own bonus token
             self.spec_accepted += emitted - len(actives)
         self.tokens_emitted += emitted
-        # the latency every decoding slot experienced this tick — admission
-        # work included, which is exactly what the chunked-prefill
-        # interleave rule bounds (<= one chunk per tick)
-        self._tick_times.append(time.monotonic() - t_tick)
         return emitted
 
     def warm_admission(self):
@@ -1404,11 +1404,6 @@ class ServeEngine:
             "ttft_p99_s": pct(ttfts, 99),
             "tpot_p50_s": pct(tpots, 50),
             "tpot_p99_s": pct(tpots, 99),
-            # inter-token latency: wall time of each decode TICK (admission
-            # work included) — the stall a running slot actually observes;
-            # stop-the-world prefill shows up in the p99
-            "itl_p50_s": pct(self._tick_times, 50),
-            "itl_p99_s": pct(self._tick_times, 99),
             # cache pressure (live tokens / allocated cache tokens, mean
             # over decode steps) + prefix-cache effectiveness
             "kv": self.kv,
@@ -1428,7 +1423,6 @@ class ServeEngine:
             "acceptance_rate": (self.spec_accepted / self.spec_drafted
                                 if self.spec_drafted else 0.0),
             "tokens_per_step": decoded / self.steps if self.steps else 0.0,
-            "draft_overhead_s": self.draft_time_s,
             # tensor-parallel footprint: shape None == single device;
             # per-device bytes < total is the memory headroom TP buys
             "mesh_shape": (tuple(self.mesh.devices.shape)
@@ -1457,8 +1451,6 @@ class ServeEngine:
         self.spec_drafted = 0
         self.spec_accepted = 0
         self.tokens_emitted = 0
-        self.draft_time_s = 0.0
-        self._tick_times = []
         if self.prefix is not None:
             self.prefix.lookups = 0
             self.prefix.hits = 0

@@ -446,5 +446,5 @@ def test_engine_stats_report_cache_pressure():
     assert 0.0 < stats["kv_memory_utilization"] <= 1.0
     assert stats["kv_capacity_tokens"] == eng.allocator.capacity_tokens
     assert stats["kv_peak_live_tokens"] > 0
-    assert "prefix_hit_rate" in stats and "itl_p99_s" in stats
+    assert "prefix_hit_rate" in stats
     assert eng.kv_pressure()["kv_live_tokens"] == 0    # drained
