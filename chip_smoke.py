@@ -10,7 +10,10 @@ d_ff 2560, vocab 49152, tied; random weights from ``--seed``):
    (``interpret=False``) and are compared on the device with their
    ``ref.py`` oracles under written tolerances; the engine's lowered
    decode step must contain ``tpu_custom_call`` (a Pallas kernel), so a
-   kernel cannot silently have become a reference.
+   kernel cannot silently have become a reference; and the decode step of
+   the 32x2048 image, compiled for this chip, must hold no ``copy`` shaped
+   like a KV pool (a layer's slice or all layers), so the paged kernel
+   reads the pools in the layout the device stores them in.
 2. pilot — ``ClusterSim`` -> ``TaskRepo.submit(PayloadImage(...,
    smoke=False, flags=(("attn_impl", "pallas"),)))`` -> pilot ->
    ``run_wrapper`` -> ``ServeEngine`` (paged KV, Pallas prefill + paged
@@ -46,6 +49,8 @@ SLOTS, MAX_LEN = 8, 1024
 N_REQUESTS = 8
 PROMPT_LEN = (64, 512)            # inclusive bounds
 BUDGET = (16, 64)
+
+POOL_SLOTS, POOL_MAX_LEN = 32, 2048   # the benchmark's big serve image
 
 TP_ARCH = "granite-moe-3b-a800m"
 TP_LAYERS = 16                    # of 32: f32 weights of the full depth do
@@ -154,7 +159,7 @@ def kernel_phase(cfg, seed: int, *, interpret: bool = False) -> dict:
     from repro.kernels.flash_attention.ops import flash_attention
     from repro.kernels.flash_attention.ref import attention_ref
     from repro.kernels.paged_attention.ops import (
-        paged_decode_attention, paged_verify_attention)
+        paged_decode_attention, paged_verify_attention, row_width)
     from repro.kernels.paged_attention.ref import (
         paged_decode_attention_ref, paged_verify_attention_ref)
 
@@ -167,8 +172,8 @@ def kernel_phase(cfg, seed: int, *, interpret: bool = False) -> dict:
     q = normal(ks[0], (1, 512, H, Dh))
     k = normal(ks[1], (1, 512, K, Dh))
     v = normal(ks[2], (1, 512, K, Dh))
-    kp = normal(ks[3], (nb, bs, K, Dh))
-    vp = normal(ks[4], (nb, bs, K, Dh))
+    kp = normal(ks[3], (nb, bs, row_width(K, Dh)))
+    vp = normal(ks[4], (nb, bs, row_width(K, Dh)))
     rng = np.random.default_rng(seed)
     # every slot owns its own physical blocks, in scrambled order
     tables = jnp.asarray(1 + rng.permutation(SLOTS * mb).reshape(SLOTS, mb),
@@ -185,11 +190,15 @@ def kernel_phase(cfg, seed: int, *, interpret: bool = False) -> dict:
             lambda *a: flash_attention(*a, interpret=interpret),
             lambda *a: attention_ref(*a), (q, k, v)),
         "paged_decode": (
-            lambda *a: paged_decode_attention(*a, interpret=interpret),
-            paged_decode_attention_ref, (qd, kp, vp, tables, lens)),
+            lambda *a: paged_decode_attention(*a, kv_heads=K,
+                                              interpret=interpret),
+            lambda *a: paged_decode_attention_ref(*a, kv_heads=K),
+            (qd, kp, vp, tables, lens)),
         "paged_verify": (
-            lambda *a: paged_verify_attention(*a, interpret=interpret),
-            paged_verify_attention_ref, (qv, kp, vp, tables, off)),
+            lambda *a: paged_verify_attention(*a, kv_heads=K,
+                                              interpret=interpret),
+            lambda *a: paged_verify_attention_ref(*a, kv_heads=K),
+            (qv, kp, vp, tables, off)),
     }
     out = {}
     for name, (kernel, ref, args) in cases.items():
@@ -220,6 +229,41 @@ def decode_step_has_kernel(cfg) -> bool:
     text = make_engine_step(bundle, MAX_LEN).lower(
         params, state, active, budget).as_text()
     return "tpu_custom_call" in text
+
+
+def decode_step_pool_copies(cfg, slots: int, max_len: int, *,
+                            sharding=None) -> list[str]:
+    """Compile the engine's decode step for ``cfg`` at ``slots x max_len``
+    (paged KV, default block size) on abstract shapes and return the
+    copies in its HLO shaped like a KV pool
+    (:func:`repro.launch.hlo_stats.pool_copies`).  Prints the layout the
+    compiled step takes each pool in.  ``sharding`` places the shapes
+    (a described chip); None compiles for the default device."""
+    import jax
+    import jax.numpy as jnp
+    from repro.launch.hlo_stats import pool_copies
+    from repro.models.api import (build_model, default_num_blocks,
+                                  init_decode_state)
+    from repro.serving.engine import make_engine_step
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    bundle = build_model(cfg)
+    params = jax.tree.map(spec, jax.eval_shape(bundle.init,
+                                               jax.random.key(0)))
+    state = jax.tree.map(spec, jax.eval_shape(lambda: init_decode_state(
+        cfg, slots, max_len, kv="paged")))
+    active = spec(jax.ShapeDtypeStruct((slots,), bool))
+    budget = spec(jax.ShapeDtypeStruct((slots,), jnp.int32))
+    compiled = make_engine_step(bundle, max_len).lower(
+        params, state, active, budget).compile()
+    formats = compiled.input_formats[0][1]["cache"][0]
+    for key, fmt in sorted(formats.items()):
+        say(f"decode step {slots}x{max_len}: pool {key} "
+            f"{state['cache'][0][key].shape} layout {fmt.layout}")
+    return pool_copies(compiled.as_text(),
+                       default_num_blocks(slots, max_len, 16))
 
 
 # --------------------------------------------------------------------------
@@ -334,6 +378,11 @@ def one_chip(seed: int, meter: CompileMeter) -> None:
     if not decode_step_has_kernel(cfg):
         raise SmokeFailure("the decode step's HLO has no tpu_custom_call")
     say("decode step HLO contains tpu_custom_call")
+    copies = decode_step_pool_copies(cfg, POOL_SLOTS, POOL_MAX_LEN)
+    say(f"decode step {POOL_SLOTS}x{POOL_MAX_LEN}: {len(copies)} pool-sized "
+        f"copies {copies}")
+    if copies:
+        raise SmokeFailure("the decode step re-lays out or copies KV pools")
     trace = make_trace(cfg.vocab_size, N_REQUESTS, PROMPT_LEN, BUDGET, seed)
     t0 = time.monotonic()
     (run,) = serve_through_pilot([img], [trace])

@@ -1,12 +1,21 @@
 """Paged flash-decode: few-query attention over a block-pool KV cache.
 
-The pool ``(num_blocks, block_size, K, Dh)`` is shared by every sequence;
+The pool ``(num_blocks, block_size, W)`` is shared by every sequence;
 a per-row block table maps logical position ``p`` of batch row ``b`` to
-``pool[table[b, p // bs], p % bs]``.  Grid = (B, mb): the last axis walks
-the row's block table sequentially, carrying the online-softmax state of
-every KV head in VMEM scratch.  Each grid cell streams one whole physical
-block, all K heads at once, so the block's last two dims ``(K, Dh)`` are
-the pool's own — the TPU tiling rule holds for any head count.  Both the
+``pool[table[b, p // bs], p % bs]``.  A position's K heads sit merged in
+the minor dim, head ``h`` in lanes ``[h*Dh, (h+1)*Dh)``, and ``W`` is
+``K*Dh`` rounded up to whole 128-lane rows (`row_width`).  The TPU keeps
+an array in the layout whose minor-dim tiles pad least: minor dims
+``(K, Dh)`` = (5, 64), or a merged 320-lane row, pad more than the
+4097-block axis would, so such a pool is stored block-axis-minor, which
+this kernel cannot stream, and XLA re-lays out every layer's pool around
+every call.  Whole 128-lane rows tile densely, so the pool stays
+row-major and the kernel reads it in place.  Grid = (B, mb): the last
+axis walks the row's block table sequentially, carrying the
+online-softmax state of every KV head in VMEM scratch.  Each grid cell
+streams one whole physical block, all K heads at once: its last two dims
+``(block_size, W)`` are the pool's own, so the TPU tiling rule holds for
+any head count, and each head is a static lane slice of it.  Both the
 query offsets AND the block tables arrive via scalar prefetch (SMEM), so
 the physical block to stream into VMEM is chosen by the BlockSpec
 index_map — the gather never materializes a contiguous copy of the
@@ -32,6 +41,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128
+
+
+def row_width(kv_heads: int, head_dim: int) -> int:
+    """Minor dim of a paged pool: the ``kv_heads`` heads merged, rounded
+    up to whole 128-lane rows once they span more than one.  Rows of one
+    lane-row or less stay as they are: padding them would multiply the
+    pool, and only tiny test models have them."""
+    kd = kv_heads * head_dim
+    return kd if kd <= LANES else -(-kd // LANES) * LANES
 
 
 def _paged_kernel(off_ref, btab_ref, q_ref, k_ref, v_ref, o_ref,
@@ -63,12 +82,14 @@ def _paged_kernel(off_ref, btab_ref, q_ref, k_ref, v_ref, o_ref,
                                                1)
         valid = tcol <= off_ref[b] + s_idx
         # zero rows past the reach so 0-weight garbage can't poison p@v
+        head_dim = q_ref.shape[3]
         vrow = base + jax.lax.broadcasted_iota(
-            jnp.int32, (block_size, v_ref.shape[3]), 0) <= reach
+            jnp.int32, (block_size, head_dim), 0) <= reach
         for h in range(n_kv):
+            lanes = pl.ds(h * head_dim, head_dim)
             q = q_ref[0, h]                               # (rows, Dh)
-            k = k_ref[0, :, h, :]                         # (block_size, Dh)
-            v = jnp.where(vrow, v_ref[0, :, h, :], 0.0)
+            k = k_ref[0, :, lanes]                        # (block_size, Dh)
+            v = jnp.where(vrow, v_ref[0, :, lanes], 0.0)
             s = jax.lax.dot_general(
                 q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
                 (((1,), (1,)), ((), ())),
@@ -100,10 +121,12 @@ def paged_attention_kernel(q, k_pool, v_pool, block_tables, q_off, *,
                            group, interpret=False):
     """q: (B, K, n_q*G, Dh), row ``s*G + g`` is query ``s`` (absolute
     position ``q_off[b] + s``) of kv-group head ``g``; pools:
-    (nb, block_size, K, Dh); block_tables: (B, mb) int32 physical block
-    ids; q_off: (B,) int32 position of each row's first query."""
+    (nb, block_size, W), head ``h`` in lanes ``[h*Dh, (h+1)*Dh)`` of
+    ``W >= K*Dh``; block_tables: (B, mb) int32 physical block ids; q_off:
+    (B,) int32 position of each row's first query."""
     B, K, R, Dh = q.shape
-    block_size = k_pool.shape[1]
+    _, block_size, W = k_pool.shape
+    assert K * Dh <= W, (q.shape, k_pool.shape)
     mb = block_tables.shape[1]
     scale = 1.0 / (Dh ** 0.5)
 
@@ -117,10 +140,10 @@ def paged_attention_kernel(q, k_pool, v_pool, block_tables, q_off, *,
             pl.BlockSpec((1, K, R, Dh), lambda b, ti, off, btab: (b, 0, 0, 0)),
             # the paged gather: the physical block streamed into VMEM is
             # picked from the prefetched table, per grid cell
-            pl.BlockSpec((1, block_size, K, Dh),
-                         lambda b, ti, off, btab: (btab[b, ti], 0, 0, 0)),
-            pl.BlockSpec((1, block_size, K, Dh),
-                         lambda b, ti, off, btab: (btab[b, ti], 0, 0, 0)),
+            pl.BlockSpec((1, block_size, W),
+                         lambda b, ti, off, btab: (btab[b, ti], 0, 0)),
+            pl.BlockSpec((1, block_size, W),
+                         lambda b, ti, off, btab: (btab[b, ti], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, K, R, Dh),
                                lambda b, ti, off, btab: (b, 0, 0, 0)),

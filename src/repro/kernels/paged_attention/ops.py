@@ -8,27 +8,29 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.paged_attention.kernel import paged_attention_kernel
-from repro.runtime.mesh import MODEL_AXIS
+from repro.kernels.paged_attention.kernel import (paged_attention_kernel,
+                                                  row_width)
+from repro.runtime.mesh import MODEL_AXIS, mesh_axis_size, tp_heads
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("kv_heads", "interpret"))
 def paged_decode_attention(q, k_pool, v_pool, block_tables, cache_len, *,
-                           interpret=None):
-    """q: (B,H,Dh) one new token per sequence; pools: (nb, bs, K, Dh) shared
-    block pool; block_tables: (B, mb) int32; cache_len: scalar or (B,) valid
-    count.  Returns (B,H,Dh).
+                           kv_heads, interpret=None):
+    """q: (B,H,Dh) one new token per sequence; pools: (nb, bs, W) shared
+    block pool, kv head ``h`` in lanes ``[h*Dh, (h+1)*Dh)`` (`row_width`
+    gives ``W``); block_tables: (B, mb) int32; cache_len: scalar or (B,)
+    valid count.  Returns (B,H,Dh).
 
     The logical sequence of row ``b`` is ``pool[table[b, p // bs], p % bs]``
     for ``p < cache_len[b]``; table entries past the row's allocation point
     at the reserved scratch block (id 0) and are masked out by the ragged
     lengths, so they are never read into the softmax."""
     B, H, Dh = q.shape
-    K = k_pool.shape[2]
+    K = kv_heads
     assert H % K == 0, (H, K)
     G = H // K
     if interpret is None:
@@ -41,19 +43,20 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, cache_len, *,
     return o.reshape(B, H, Dh)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("kv_heads", "interpret"))
 def paged_verify_attention(q, k_pool, v_pool, block_tables, q_off, *,
-                           interpret=None):
+                           kv_heads, interpret=None):
     """k-query flash-decode for speculative verify.  q: (B,S,H,Dh) — the
     S = k+1 verify queries of each row, query ``s`` at absolute position
-    ``q_off[b] + s``; pools: (nb, bs, K, Dh); block_tables: (B, mb) int32;
-    q_off: scalar or (B,) base positions.  Returns (B,S,H,Dh).
+    ``q_off[b] + s``; pools: (nb, bs, W) as `paged_decode_attention`'s;
+    block_tables: (B, mb) int32; q_off: scalar or (B,) base positions.
+    Returns (B,S,H,Dh).
 
     One walk of the row's block table serves all S queries (a staircase
     causal mask instead of S ragged lengths), so the verify step streams
     each KV block from HBM once, not S times."""
     B, S, H, Dh = q.shape
-    K = k_pool.shape[2]
+    K = kv_heads
     assert H % K == 0, (H, K)
     G = H // K
     if interpret is None:
@@ -72,32 +75,48 @@ def paged_verify_attention(q, k_pool, v_pool, block_tables, q_off, *,
 # --------------------------------------------------------------------------
 # Each mesh shard runs the SAME Pallas kernel on its local contiguous head
 # slice: q on dim 1 (decode) / dim 2 (verify) over "model", pools on their
-# K dim (2), block tables + lengths replicated (they are the scalar-prefetch
-# operands — every shard walks the same table).  The contiguous-heads split
-# aligns with the kv-group mapping (query head h attends kv head h // G), so
-# shard s owns query heads [s*H/m, (s+1)*H/m) and exactly the kv heads
-# [s*K/m, (s+1)*K/m) they attend — no cross-shard communication, and every
-# per-head softmax is bitwise identical to the single-device kernel.
+# merged-heads dim (2), block tables + lengths replicated (they are the
+# scalar-prefetch operands — every shard walks the same table).  The
+# contiguous-heads split aligns with the kv-group mapping (query head h
+# attends kv head h // G), so shard s owns query heads [s*H/m, (s+1)*H/m)
+# and exactly the kv heads [s*K/m, (s+1)*K/m) they attend, which are its
+# lanes of the pool when the pool has no padding lanes (W == K*Dh) — no
+# cross-shard communication, and every per-head softmax is bitwise
+# identical to the single-device kernel.
 # check_vma=False: pallas_call inside shard_map cannot prove replication.
-# Eligibility is :func:`repro.runtime.mesh.tp_heads`.
+# Eligibility is :func:`heads_shard`.
+
+def heads_shard(mesh, kv_heads: int, num_heads: int, head_dim: int) -> bool:
+    """True iff the paged kernels and pools can be head-sharded on
+    ``mesh``: whole kv-groups per shard (:func:`repro.runtime.mesh.tp_heads`)
+    and a pool row with no padding lanes, so an even split of the row is
+    an even split of the heads."""
+    return (tp_heads(mesh, kv_heads, num_heads)
+            and row_width(kv_heads, head_dim) == kv_heads * head_dim)
+
 
 def _len_spec(x) -> P:
     return P() if jnp.ndim(x) == 0 else P(*([None] * jnp.ndim(x)))
 
 
+def _shard_heads(mesh, kv_heads: int) -> int:
+    return kv_heads // mesh_axis_size(mesh, MODEL_AXIS)
+
+
 def paged_decode_attention_tp(q, k_pool, v_pool, block_tables, cache_len,
-                              mesh, *, interpret=None):
+                              mesh, *, kv_heads, interpret=None):
     """Head-sharded paged_decode_attention under shard_map.  Same contract;
-    q (B,H,Dh) sharded on H, pools (nb,bs,K,Dh) sharded on K, output
-    (B,H,Dh) sharded on H.  Requires
-    :func:`repro.runtime.mesh.tp_heads`."""
+    q (B,H,Dh) sharded on H, pools (nb,bs,K*Dh) sharded on their heads,
+    output (B,H,Dh) sharded on H.  Requires :func:`heads_shard`."""
     if interpret is None:
         interpret = not _on_tpu()
     fn = jax.shard_map(
-        functools.partial(paged_decode_attention, interpret=interpret),
+        functools.partial(paged_decode_attention,
+                          kv_heads=_shard_heads(mesh, kv_heads),
+                          interpret=interpret),
         mesh=mesh,
-        in_specs=(P(None, MODEL_AXIS, None), P(None, None, MODEL_AXIS, None),
-                  P(None, None, MODEL_AXIS, None), P(None, None),
+        in_specs=(P(None, MODEL_AXIS, None), P(None, None, MODEL_AXIS),
+                  P(None, None, MODEL_AXIS), P(None, None),
                   _len_spec(cache_len)),
         out_specs=P(None, MODEL_AXIS, None),
         check_vma=False)
@@ -105,17 +124,19 @@ def paged_decode_attention_tp(q, k_pool, v_pool, block_tables, cache_len,
 
 
 def paged_verify_attention_tp(q, k_pool, v_pool, block_tables, q_off,
-                              mesh, *, interpret=None):
+                              mesh, *, kv_heads, interpret=None):
     """Head-sharded paged_verify_attention under shard_map.  q (B,S,H,Dh)
-    sharded on H; pools on K; output sharded on H."""
+    sharded on H; pools on their heads; output sharded on H."""
     if interpret is None:
         interpret = not _on_tpu()
     fn = jax.shard_map(
-        functools.partial(paged_verify_attention, interpret=interpret),
+        functools.partial(paged_verify_attention,
+                          kv_heads=_shard_heads(mesh, kv_heads),
+                          interpret=interpret),
         mesh=mesh,
         in_specs=(P(None, None, MODEL_AXIS, None),
-                  P(None, None, MODEL_AXIS, None),
-                  P(None, None, MODEL_AXIS, None), P(None, None),
+                  P(None, None, MODEL_AXIS),
+                  P(None, None, MODEL_AXIS), P(None, None),
                   _len_spec(q_off)),
         out_specs=P(None, None, MODEL_AXIS, None),
         check_vma=False)

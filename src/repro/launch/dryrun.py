@@ -212,8 +212,11 @@ def run_serve_cell(arch: str, *, mesh_shape: tuple = (1, 1),
         jax.tree_util.tree_map_with_path(one, tree)
         return total[0], per_dev[0]
 
+    def state_factor(path, shape, msz):
+        return shd.serve_state_shard_factor(path, shape, msz, cfg)
+
     p_total, p_dev = _account(params, shd.serve_param_shard_factor)
-    s_total, s_dev = _account(state, shd.serve_state_shard_factor)
+    s_total, s_dev = _account(state, state_factor)
     kv_leaves = {"kp", "vp", "ckvp", "kropep", "k", "v", "ckv", "krope"}
     kv_total = [0]
     kv_dev = [0]
@@ -223,7 +226,7 @@ def run_serve_cell(arch: str, *, mesh_shape: tuple = (1, 1),
             return
         b = int(leaf.size) * leaf.dtype.itemsize
         kv_total[0] += b
-        kv_dev[0] += b // shd.serve_state_shard_factor(path, leaf.shape, msz)
+        kv_dev[0] += b // state_factor(path, leaf.shape, msz)
     jax.tree_util.tree_map_with_path(kv_one, state)
 
     # decode FLOPs: one token per slot per step.  The column-parallel

@@ -141,6 +141,29 @@ def collective_stats(hlo_text: str) -> dict:
     }
 
 
+def pool_copies(hlo_text: str, num_blocks: int) -> list[str]:
+    """The ``copy`` instructions of ``hlo_text`` whose result is shaped
+    like a paged KV pool of ``num_blocks`` blocks: a layer's pool
+    ``[nb, ...]``, one sliced out of the stacked pools ``[1, nb, ...]``,
+    or the stacked pools whole ``[L, nb, ...]``.  Returns each as
+    ``"name = type"``; a decode step that reads and writes its pools in
+    place has none."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = _INSTR_RE.match(line)
+        if not m:
+            continue
+        name, rhs = m.groups()
+        st = _split_type_op(rhs)
+        if st is None or st[1] not in ("copy", "copy-start"):
+            continue
+        sm = _SHAPE_RE.search(st[0])
+        dims = [int(d) for d in sm.group(2).split(",") if d] if sm else []
+        if num_blocks in dims[:2]:
+            out.append(f"{name} = {st[0]}")
+    return out
+
+
 def cost_summary(compiled) -> dict:
     """Pull flops / bytes out of compiled.cost_analysis() (per-device)."""
     try:

@@ -140,7 +140,7 @@ def init_decode_state(cfg: ArchConfig, batch: int, max_len: int, *,
             (batch, max_len // block_size), jnp.int32)
     if mesh is not None:
         from repro.runtime.sharding import serve_state_shardings
-        shardings = serve_state_shardings(state, mesh)
+        shardings = serve_state_shardings(state, mesh, cfg)
         state = jax.tree.map(jax.device_put, state, shardings)
     return state
 

@@ -365,13 +365,29 @@ def _row_positions(pos, batch: int):
 #
 # The paged cache is a shared pool ``(num_blocks, block_size, ...)`` plus a
 # per-row block table ``(B, max_blocks)``: logical position ``p`` of row
-# ``b`` lives at ``pool[table[b, p // bs], p % bs]``.  Block 0 is a
+# ``b`` lives at ``pool[table[b, p // bs], p % bs]``.  A GQA pool stores a
+# position's K heads merged in one row of ``row_width(K, Dh)`` lanes, head
+# ``h`` in lanes ``[h*Dh, (h+1)*Dh)``: the layout the paged kernel streams
+# and the one the TPU keeps the pool in, so no layer's pool is re-laid out
+# around the kernel (see `repro.kernels.paged_attention.kernel`).  Writes
+# merge the new rows' heads; gathers split them again.  Block 0 is a
 # reserved scratch block — free slots keep decoding over garbage (cheaper
 # than masking the batched matmuls, same as the dense engine) and their
 # writes land there, never in a live request's blocks.  The XLA fallback
 # gathers each row's logical ``(max_blocks * bs,)`` view, which the
 # allocator sizes to the engine ``max_len`` so the attend math (shapes,
 # masks, reduction order) is bitwise-identical to the dense ring path.
+
+
+def pool_rows(pool, new, lead: int):
+    """``new`` in the pool's row format: its ``lead`` leading dims kept and
+    the rest merged, (..., K, Dh) -> (..., K*Dh), zero-padded to the pool's
+    row width.  A latent pool's rows pass through."""
+    rows = new.reshape(new.shape[:lead] + (-1,)).astype(pool.dtype)
+    pad = pool.shape[-1] - rows.shape[-1]
+    if pad:
+        rows = jnp.pad(rows, [(0, 0)] * lead + [(0, pad)])
+    return rows
 
 
 def _paged_write_rows(pool, new, block_tables, pos):
@@ -382,16 +398,18 @@ def _paged_write_rows(pool, new, block_tables, pos):
     mb = block_tables.shape[1]
     pb = jnp.take_along_axis(
         block_tables, ((pos // bs) % mb)[:, None], axis=1)[:, 0]
-    return pool.at[pb, pos % bs].set(new[:, 0].astype(pool.dtype))
+    return pool.at[pb, pos % bs].set(pool_rows(pool, new[:, 0], 1))
 
 
-def _paged_gather(pool, block_tables):
-    """Materialize each row's logical view: (B, mb * bs, ...).  XLA
-    fallback only — the Pallas kernel gathers via scalar prefetch.  One
-    definition shared with the kernel oracle so the fallback and the
-    oracle can never diverge."""
+def _paged_gather(pool, block_tables, cfg=None):
+    """Materialize each row's logical view: (B, mb * bs, ...), a GQA
+    pool's row read back as cfg's (K, Dh) heads.  XLA fallback only —
+    the Pallas kernel gathers via scalar prefetch.  One definition shared
+    with the kernel oracle so the fallback and the oracle can never
+    diverge."""
     from repro.kernels.paged_attention.ref import gather_kv
-    return gather_kv(pool, block_tables)
+    heads = None if cfg is None else (cfg.num_kv_heads, cfg.head_dim)
+    return gather_kv(pool, block_tables, heads)
 
 
 def _paged_write_chunk(pool, new, table_row, positions):
@@ -400,7 +418,7 @@ def _paged_write_chunk(pool, new, table_row, positions):
     bs = pool.shape[1]
     mb = table_row.shape[0]
     pb = table_row[(positions // bs) % mb]
-    return pool.at[pb, positions % bs].set(new.astype(pool.dtype))
+    return pool.at[pb, positions % bs].set(pool_rows(pool, new, 1))
 
 
 def _ring_write_rows(cache, new, slot):
@@ -415,7 +433,7 @@ def _ring_write_rows(cache, new, slot):
 def attention_decode(x, p, cfg, cache, pos, *, rope_theta=None,
                      window=None, block_tables=None, compute=jnp.bfloat16):
     """One decode step.  x: (B,1,D); cache {"k","v"}: (B,T,K,Dh) dense ring
-    or {"kp","vp"}: (nb,bs,K,Dh) paged pool (then ``block_tables`` (B,mb)
+    or {"kp","vp"}: (nb,bs,W) paged pool (then ``block_tables`` (B,mb)
     maps rows to blocks); pos: scalar or (B,) absolute position(s) of the
     new token — per-row positions are the continuous-batching path.
     Returns (out, new_cache)."""
@@ -438,20 +456,22 @@ def attention_decode(x, p, cfg, cache, pos, *, rope_theta=None,
         cache_len = jnp.minimum(pos + 1, T)
         if cfg.attn_impl == "pallas":
             from repro.kernels.paged_attention.ops import (
-                paged_decode_attention, paged_decode_attention_tp)
-            from repro.runtime.mesh import tp_heads
+                heads_shard, paged_decode_attention,
+                paged_decode_attention_tp)
             from repro.runtime.sharding import active_serve_mesh
             mesh = active_serve_mesh()
-            if tp_heads(mesh, cfg.num_kv_heads, cfg.num_heads):
+            K = cfg.num_kv_heads
+            if heads_shard(mesh, K, cfg.num_heads, cfg.head_dim):
                 out = paged_decode_attention_tp(q[:, 0], k_pool, v_pool,
                                                 block_tables, cache_len,
-                                                mesh)[:, None]
+                                                mesh, kv_heads=K)[:, None]
             else:
                 out = paged_decode_attention(q[:, 0], k_pool, v_pool,
-                                             block_tables, cache_len)[:, None]
+                                             block_tables, cache_len,
+                                             kv_heads=K)[:, None]
         else:
-            out = decode_attend(q, _paged_gather(k_pool, block_tables),
-                                _paged_gather(v_pool, block_tables),
+            out = decode_attend(q, _paged_gather(k_pool, block_tables, cfg),
+                                _paged_gather(v_pool, block_tables, cfg),
                                 cache_len, window=window)
         out = jnp.einsum("bshk,hkd->bsd", constrain_replicated(out), p["wo"].astype(compute))
         return out, {"kp": k_pool, "vp": v_pool}
@@ -488,7 +508,7 @@ def _paged_write_seq(pool, new, block_tables, pos):
     inb = positions < mb * bs
     blk = jnp.where(inb, positions // bs, 0)
     pb = jnp.where(inb, jnp.take_along_axis(block_tables, blk, axis=1), 0)
-    return pool.at[pb, positions % bs].set(new.astype(pool.dtype))
+    return pool.at[pb, positions % bs].set(pool_rows(pool, new, 2))
 
 
 def attention_verify(x, p, cfg, cache, pos, *, block_tables,
@@ -523,18 +543,19 @@ def attention_verify(x, p, cfg, cache, pos, *, block_tables,
     T = block_tables.shape[1] * k_pool.shape[1]
     if cfg.attn_impl == "pallas":
         from repro.kernels.paged_attention.ops import (
-            paged_verify_attention, paged_verify_attention_tp)
-        from repro.runtime.mesh import tp_heads
+            heads_shard, paged_verify_attention, paged_verify_attention_tp)
         from repro.runtime.sharding import active_serve_mesh
         mesh = active_serve_mesh()
-        if tp_heads(mesh, cfg.num_kv_heads, cfg.num_heads):
+        K = cfg.num_kv_heads
+        if heads_shard(mesh, K, cfg.num_heads, cfg.head_dim):
             out = paged_verify_attention_tp(q, k_pool, v_pool, block_tables,
-                                            pos, mesh)
+                                            pos, mesh, kv_heads=K)
         else:
-            out = paged_verify_attention(q, k_pool, v_pool, block_tables, pos)
+            out = paged_verify_attention(q, k_pool, v_pool, block_tables,
+                                         pos, kv_heads=K)
     else:
-        kg = _paged_gather(k_pool, block_tables)
-        vg = _paged_gather(v_pool, block_tables)
+        kg = _paged_gather(k_pool, block_tables, cfg)
+        vg = _paged_gather(v_pool, block_tables, cfg)
         out = jnp.concatenate(
             [decode_attend(q[:, s:s + 1], kg, vg,
                            jnp.minimum(pos + s + 1, T))
@@ -624,10 +645,13 @@ def init_kv_cache_paged(cfg, batch: int, max_len: int, num_blocks: int,
             "kropep": jnp.zeros((num_blocks, block_size, s.qk_rope_head_dim),
                                 dtype),
         }
-    K, Dh = cfg.num_kv_heads, cfg.head_dim
+    # heads merged in one lane-padded row: the kernel's layout (see the
+    # paged write helpers above)
+    from repro.kernels.paged_attention.kernel import row_width
+    W = row_width(cfg.num_kv_heads, cfg.head_dim)
     return {
-        "kp": jnp.zeros((num_blocks, block_size, K, Dh), dtype),
-        "vp": jnp.zeros((num_blocks, block_size, K, Dh), dtype),
+        "kp": jnp.zeros((num_blocks, block_size, W), dtype),
+        "vp": jnp.zeros((num_blocks, block_size, W), dtype),
     }
 
 
@@ -814,8 +838,8 @@ def attention_prefill_chunk(x, p, cfg, cache, table_row, slot, q_offset,
     if "kp" in cache:                        # full attention: paged pool
         k_pool = _paged_write_chunk(cache["kp"], k[0], table_row, positions)
         v_pool = _paged_write_chunk(cache["vp"], v[0], table_row, positions)
-        kg = _paged_gather(k_pool, table_row[None])      # (1,T,K,Dh)
-        vg = _paged_gather(v_pool, table_row[None])
+        kg = _paged_gather(k_pool, table_row[None], cfg)     # (1,T,K,Dh)
+        vg = _paged_gather(v_pool, table_row[None], cfg)
         out = _chunk_attend(q, kg, vg, positions)
         new_cache = {"kp": k_pool, "vp": v_pool}
     else:                                    # SWA: dense rolling ring

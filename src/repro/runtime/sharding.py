@@ -254,7 +254,14 @@ def _stacked_cache(path) -> bool:
     return False
 
 
-def serve_state_shardings(state_specs, mesh: Mesh):
+def _pool_heads_split(cfg, shape, msz: int) -> bool:
+    """A paged GQA pool row (nb, bs, W) splits ``msz`` ways into whole kv
+    heads: the axis divides K and W holds the K heads with no padding."""
+    K = cfg.num_kv_heads
+    return K % msz == 0 and shape[-1] == K * cfg.head_dim
+
+
+def serve_state_shardings(state_specs, mesh: Mesh, cfg):
     """Serve-engine decode state under tensor parallelism: KV pools shard on
     the HEAD dim over "model", never on the sequence/block dim.
 
@@ -266,13 +273,16 @@ def serve_state_shardings(state_specs, mesh: Mesh):
     kernel — each shard owns whole heads.
 
     Rules (dims in trailing/negative indexing, stacked group dim invariant):
-      kp/vp       (nb, bs, K, Dh)        -> K (dim -2) over "model"
+      kp/vp       (nb, bs, W)            -> heads (dim -1) over "model"
       ckvp        (nb, bs, r_latent)     -> latent (dim -1) over "model"
       kropep      (nb, bs, d_rope)       -> latent (dim -1) over "model"
       k/v dense   (B, T, K, Dh)          -> K (dim -2) over "model"
       ckv/krope dense (B, T, r)          -> latent (dim -1) over "model"
       ssd/conv / token / pos / block_tables -> replicated
-    Every rule degrades to replication when the axis doesn't divide the dim.
+    Every rule degrades to replication when the axis doesn't divide the dim;
+    a kp/vp row (``cfg``'s K heads merged) splits only when the axis
+    divides K and the row has no padding lanes, so every shard holds
+    whole heads (`repro.kernels.paged_attention.ops.heads_shard`).
     """
     msz = axis_size(mesh, MODEL_AXIS)
 
@@ -283,8 +293,8 @@ def serve_state_shardings(state_specs, mesh: Mesh):
         spec: list = [None] * ndim
         if msz > 1 and ndim >= 2:
             if name in ("kp", "vp"):
-                if shape[-2] % msz == 0:
-                    spec[ndim - 2] = MODEL_AXIS
+                if _pool_heads_split(cfg, shape, msz):
+                    spec[ndim - 1] = MODEL_AXIS
             elif name in ("ckvp", "kropep"):
                 if shape[-1] % msz == 0:
                     spec[ndim - 1] = MODEL_AXIS
@@ -352,7 +362,8 @@ def serve_param_shard_factor(path, shape, model_axis_size: int) -> int:
             if shape[tp_dim % ndim] % model_axis_size == 0 else 1)
 
 
-def serve_state_shard_factor(path, shape, model_axis_size: int) -> int:
+def serve_state_shard_factor(path, shape, model_axis_size: int,
+                             cfg) -> int:
     """Pure-divisor mirror of :func:`serve_state_shardings`: KV pools and
     dense caches split on the head/latent dim over the model axis when it
     divides, everything else (ssd/conv/token/pos/block_tables) replicates."""
@@ -362,7 +373,7 @@ def serve_state_shard_factor(path, shape, model_axis_size: int) -> int:
     if msz <= 1 or ndim < 2:
         return 1
     if name in ("kp", "vp"):
-        return msz if shape[-2] % msz == 0 else 1
+        return msz if _pool_heads_split(cfg, shape, msz) else 1
     if name in ("ckvp", "kropep"):
         return msz if shape[-1] % msz == 0 else 1
     if name in ("k", "v"):

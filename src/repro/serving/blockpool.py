@@ -178,10 +178,11 @@ class KVHandoff:
     key (``kp``/``vp`` for GQA, ``ckvp``/``kropep`` for MLA) to a host
     buffer of shape ``(groups, n_prompt_blocks, block_size, ...)`` — the
     slot's block chain gathered contiguously (device-side gather, one
-    host pull for the whole pytree).  Because chunk boundaries, padding
-    and bucket shapes are identical on both sides, scattering these
-    buffers into the importer's pool reproduces the exporter's KV rows
-    bit for bit.
+    host pull for the whole pytree).  GQA rows travel as ``(K, Dh)``
+    whatever layout the pool stores them in.  Because chunk boundaries,
+    padding and bucket shapes are identical on both sides, scattering
+    these buffers into the importer's pool reproduces the exporter's KV
+    rows bit for bit.
 
     ``block_hashes`` carries the exporter's chain-hash keys over the
     padded prompt, so the importer can (a) skip scattering blocks its own
@@ -189,7 +190,7 @@ class KVHandoff:
     blocks under the SAME keys — prefix sharing survives the handoff.
 
     ``fingerprint`` pins everything the scatter relies on (block size
-    plus every paged leaf's pool layout and dtype); an importer whose
+    plus every paged leaf's buffer shape and dtype); an importer whose
     pools disagree must reject the handoff rather than write garbage.
 
     ``first_token`` is the admission-time argmax — the one token prefill

@@ -72,6 +72,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import time
 from collections import deque
 
@@ -81,6 +82,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.models.api import build_model, init_decode_state
+from repro.models.attention import pool_rows
 from repro.serving.blockpool import BlockAllocator, KVHandoff, PrefixCache
 
 
@@ -112,13 +114,13 @@ def _traced_under_mesh(fn, mesh):
     return wrapped
 
 
-def _constrain_serve_state(mesh, state):
+def _constrain_serve_state(mesh, state, cfg):
     """Pin the decode state's output shardings inside a jitted step: pools
     stay head-sharded, tables/scalars stay replicated.  Without this, GSPMD
-    is free to re-partition donated outputs between steps, which would make
+    is free to re-partition the outputs between steps, which would make
     the engine's host-side install surgery reshard every tick."""
     from repro.runtime.sharding import serve_state_shardings
-    shardings = serve_state_shardings(state, mesh)
+    shardings = serve_state_shardings(state, mesh, cfg)
     return jax.tree.map(jax.lax.with_sharding_constraint, state, shardings)
 
 
@@ -207,6 +209,16 @@ def prefill_chunk_shapes(max_len: int, block_size: int,
     return sorted(shapes)
 
 
+# The layer scan writes every layer's new cache into a fresh stacked buffer
+# (its ys), never into the one it reads (its xs).  A donated state would
+# make XLA copy that whole buffer into the donated one at the end of each
+# step, the size of every KV pool; left undonated, the scan's buffer is the
+# step's output and the old state is freed when the engine drops it.  The
+# peak is the same: two pools live during a step either way.  Only the
+# (slots,) active and budget vectors are donated.
+_STEP_DONATE = (2, 3)
+
+
 def make_engine_step(bundle, max_len: int, mesh=None):
     """The engine's jitted decode step: decode + argmax + per-slot budget
     debit + done mask, all on device, returning one packed (2, slots) int32
@@ -220,7 +232,9 @@ def make_engine_step(bundle, max_len: int, mesh=None):
     ``activation_sharding`` context (model code then constrains activations
     and dispatches head-sharded Pallas kernels), output state shardings are
     pinned, and ``packed`` is constrained fully replicated so the engine's
-    single ``device_get`` stays one transfer — it reads one local shard."""
+    single ``device_get`` stays one transfer — it reads one local shard.
+
+    The state is not donated (see `_STEP_DONATE`)."""
     def body(params, state, active, budget):
         logits, new_state = bundle.decode(params, state)       # argmax inside
         tok = new_state["token"][:, 0]
@@ -230,7 +244,7 @@ def make_engine_step(bundle, max_len: int, mesh=None):
         return packed, new_state, active & ~done, budget
 
     if mesh is None:
-        return jax.jit(body, donate_argnums=(1, 2, 3))
+        return jax.jit(body, donate_argnums=_STEP_DONATE)
 
     from repro.runtime.sharding import activation_sharding
 
@@ -238,13 +252,13 @@ def make_engine_step(bundle, max_len: int, mesh=None):
         with activation_sharding(mesh, "serve"):
             packed, new_state, active, budget = body(
                 params, state, active, budget)
-            new_state = _constrain_serve_state(mesh, new_state)
+            new_state = _constrain_serve_state(mesh, new_state, bundle.cfg)
             packed = _replicate(mesh, packed)
             active = _replicate(mesh, active)
             budget = _replicate(mesh, budget)
         return packed, new_state, active, budget
 
-    return jax.jit(step, donate_argnums=(1, 2, 3))
+    return jax.jit(step, donate_argnums=_STEP_DONATE)
 
 
 def make_draft_step(bundle, k: int, max_len: int, mesh=None):
@@ -281,7 +295,7 @@ def make_draft_step(bundle, k: int, max_len: int, mesh=None):
     def draft_tp(params, cache, token, pos, block_tables):
         with activation_sharding(mesh, "serve"):
             toks, cache = draft(params, cache, token, pos, block_tables)
-            cache = _constrain_serve_state(mesh, cache)
+            cache = _constrain_serve_state(mesh, cache, bundle.cfg)
             # drafts feed verify device-side; replicated keeps the verify
             # trace free of a gather prologue
             toks = _replicate(mesh, toks)
@@ -327,7 +341,7 @@ def make_verify_step(bundle, max_len: int, k: int, mesh=None):
         return packed, new_state, active & ~done, budget
 
     if mesh is None:
-        return jax.jit(step, donate_argnums=(1, 2, 3))
+        return jax.jit(step, donate_argnums=_STEP_DONATE)
 
     from repro.runtime.sharding import activation_sharding
 
@@ -335,13 +349,13 @@ def make_verify_step(bundle, max_len: int, k: int, mesh=None):
         with activation_sharding(mesh, "serve"):
             packed, new_state, active, budget = step(
                 params, state, active, budget, drafts)
-            new_state = _constrain_serve_state(mesh, new_state)
+            new_state = _constrain_serve_state(mesh, new_state, bundle.cfg)
             packed = _replicate(mesh, packed)
             active = _replicate(mesh, active)
             budget = _replicate(mesh, budget)
         return packed, new_state, active, budget
 
-    return jax.jit(step_tp, donate_argnums=(1, 2, 3))
+    return jax.jit(step_tp, donate_argnums=_STEP_DONATE)
 
 
 def spec_ineligible_reason(cfg, kv: str) -> str | None:
@@ -490,7 +504,8 @@ class ServeEngine:
             self.active = jax.device_put(self.active, rep)
             # the target shardings the step functions pin; host-side
             # install surgery is repaired against this (``_ensure_sharded``)
-            self._state_shardings = serve_state_shardings(self.state, mesh)
+            self._state_shardings = serve_state_shardings(
+                self.state, mesh, cfg)
             self._rep_sharding = rep
         else:
             self._state_shardings = None
@@ -519,8 +534,9 @@ class ServeEngine:
         self.spec_accepted = 0         # of those, committed to requests
         self.tokens_emitted = 0        # total committed tokens (all modes)
 
-        # one compiled decode step for the whole engine lifetime; engine
-        # state (decode state + budget + active) is donated every step.
+        # one compiled decode step for the whole engine lifetime; budget
+        # and active are donated every step, the decode state is not
+        # (``_STEP_DONATE``).
         # A prefill-role engine never decodes (its slots turn over at the
         # handoff export) and a decode-role engine never prefills (its
         # admissions scatter imported blocks), so each drops the other
@@ -605,7 +621,7 @@ class ServeEngine:
             if mesh is not None:
                 from repro.runtime.sharding import serve_state_shardings
                 self._draft_shardings = serve_state_shardings(
-                    self._draft_cache, mesh)
+                    self._draft_cache, mesh, self.draft_cfg)
             else:
                 self._draft_shardings = None
             self._draft_prefill = draft_prefill_fn or jax.jit(
@@ -778,12 +794,14 @@ class ServeEngine:
 
     def kv_fingerprint(self) -> tuple:
         """Pool-layout identity a handoff must match: block size plus each
-        layer's paged keys with their per-block shapes and dtypes.  Two
-        engines agree iff a block gathered from one scatters into the
-        other unchanged — same arch family, head layout and KV dtype."""
+        layer's paged keys with the per-block shapes of their handoff
+        buffers and their dtypes.  Two engines agree iff a block gathered
+        from one scatters into the other unchanged — same arch family,
+        head layout and KV dtype."""
         assert self.kv == "paged", "fingerprint is a paged-pool property"
         layers = tuple(
-            tuple(sorted((k, v.shape[:1] + v.shape[2:], str(v.dtype))
+            tuple(sorted((k, v.shape[:1] + v.shape[2:3]
+                          + _host_row_shape(k, v, self.cfg), str(v.dtype))
                          for k, v in leaf.items()
                          if k in self._PAGED_KEYS))
             for leaf in self.state["cache"])
@@ -807,7 +825,7 @@ class ServeEngine:
             keys = PrefixCache.block_keys(padded, bs, n_full)
         row = self._slot_blocks[si]
         bufs = _gather_blocks(self.state["cache"], row[:n_pb],
-                              self._PAGED_KEYS)
+                              self._PAGED_KEYS, self.cfg)
         req.handoff = KVHandoff(
             rid=req.rid, prompt=np.asarray(req.prompt, np.int32),
             plen=plen, first_token=nxt, max_new_tokens=req.max_new_tokens,
@@ -888,7 +906,8 @@ class ServeEngine:
         padded = np.zeros((plen,), np.int32)
         padded[-len(prompt):] = prompt
         blocks = [
-            {k: np.zeros(v.shape[:1] + (n_pb,) + v.shape[2:], v.dtype)
+            {k: np.zeros(v.shape[:1] + (n_pb, v.shape[2])
+                         + _host_row_shape(k, v, self.cfg), v.dtype)
              for k, v in leaf.items() if k in self._PAGED_KEYS}
             for leaf in self.state["cache"]]
         return KVHandoff(
@@ -1545,16 +1564,32 @@ def _scatter_blocks(pool, src, row: list, nhit: int, block_size: int):
     if nhit >= n_pb:
         return pool
     ids = jnp.asarray(np.asarray(row[nhit:n_pb], np.int32))
-    return pool.at[:, ids].set(rows[:, nhit:].astype(pool.dtype))
+    return pool.at[:, ids].set(pool_rows(pool, rows[:, nhit:], 3))
 
 
-def _gather_blocks(cache, row: list, paged_keys) -> list:
+def _host_row_shape(key: str, pool, cfg) -> tuple:
+    """Per-position shape of pool ``key``'s KV handoff rows.  A GQA pool
+    stores its heads merged in a lane-padded row; the handoff carries them
+    split, (K, Dh), without the padding, so a handoff's bytes and format
+    do not depend on the pool's layout.  Latent pools travel as stored."""
+    if key in ("kp", "vp"):
+        return (cfg.num_kv_heads, cfg.head_dim)
+    return tuple(pool.shape[3:])
+
+
+def _gather_blocks(cache, row: list, paged_keys, cfg) -> list:
     """Gather a slot's block chain out of every layer's paged pools into
     contiguous host buffers — the export half of the KV handoff.  The
     gather (``pool[:, ids]``) runs device-side; ONE ``device_get`` over
     the whole pytree then pulls every layer in a single host transfer."""
     ids = jnp.asarray(np.asarray(row, np.int32))
-    bufs = [{k: leaf[k][:, ids] for k in leaf if k in paged_keys}
+
+    def blocks(key, pool):
+        shape = _host_row_shape(key, pool, cfg)
+        b = pool[:, ids, :, :math.prod(shape)]
+        return b.reshape(b.shape[:3] + shape)
+
+    bufs = [{k: blocks(k, leaf[k]) for k in leaf if k in paged_keys}
             for leaf in cache]
     host = jax.device_get(bufs)
     return [{k: np.asarray(v) for k, v in leaf.items()} for leaf in host]
@@ -1563,8 +1598,9 @@ def _gather_blocks(cache, row: list, paged_keys) -> list:
 def _import_blocks_paged(state, bufs: list, slot: int, plen: int,
                          next_token: int, row: list, nhit: int,
                          block_size: int):
-    """Scatter handoff buffers (per layer, ``(groups, n_pb, bs, ...)``)
-    into pool blocks ``row[nhit:n_pb]`` (prefix-hit blocks already hold
+    """Scatter handoff buffers (per layer, ``(groups, n_pb, bs, ...)``,
+    heads split as `_host_row_shape` gives them) into pool blocks
+    ``row[nhit:n_pb]`` (prefix-hit blocks already hold
     bit-identical content) and install the slot's table row, token and
     position — the import half of the KV handoff, mirroring
     `_install_slot_paged` with host buffers in place of a prefill."""
@@ -1576,8 +1612,9 @@ def _import_blocks_paged(state, bufs: list, slot: int, plen: int,
         out = dict(st_leaf)
         if ids is not None:
             for key, buf in hb.items():
-                out[key] = st_leaf[key].at[:, ids].set(
-                    jnp.asarray(buf[:, nhit:]).astype(st_leaf[key].dtype))
+                pool = st_leaf[key]
+                out[key] = pool.at[:, ids].set(
+                    pool_rows(pool, jnp.asarray(buf[:, nhit:]), 3))
         new_cache.append(out)
     mb = state["block_tables"].shape[1]
     row_arr = np.zeros((mb,), np.int32)

@@ -133,14 +133,14 @@ def test_serve_shard_factors_mirror_sharding_rules():
     def one(path, leaf):
         name = shd._leaf_name(path)
         factors.setdefault(name, set()).add(
-            shd.serve_state_shard_factor(path, leaf.shape, 2))
+            shd.serve_state_shard_factor(path, leaf.shape, 2, cfg))
     jax.tree_util.tree_map_with_path(one, state)
     # MLA latent pools split; control leaves replicate
     assert factors["ckvp"] == {2} and factors["kropep"] == {2}
     assert factors["pos"] == {1} and factors["block_tables"] == {1}
     # msz=1 never shards anything
     def check_one(path, leaf):
-        assert shd.serve_state_shard_factor(path, leaf.shape, 1) == 1
+        assert shd.serve_state_shard_factor(path, leaf.shape, 1, cfg) == 1
     jax.tree_util.tree_map_with_path(check_one, state)
 
 

@@ -173,20 +173,60 @@ def test_prefill_chunk_shapes_closed_under_prefix_offsets():
     (1, 8, 4, 32, 32, 3),        # bigger blocks
 ])
 def test_paged_kernel_matches_ref(B, H, K, Dh, bs, mb):
-    from repro.kernels.paged_attention.ops import paged_decode_attention
+    from repro.kernels.paged_attention.ops import (paged_decode_attention,
+                                                   row_width)
     from repro.kernels.paged_attention.ref import paged_decode_attention_ref
 
     rng = np.random.default_rng(0)
     nb = B * mb + 2
+    W = row_width(K, Dh)
     q = jnp.asarray(rng.normal(size=(B, H, Dh)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(nb, bs, K, Dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, K, Dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(nb, bs, W)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(nb, bs, W)), jnp.float32)
     bt = jnp.asarray(rng.integers(1, nb, size=(B, mb)), jnp.int32)
     lens = jnp.asarray(rng.integers(1, mb * bs + 1, size=(B,)), jnp.int32)
-    out = paged_decode_attention(q, kp, vp, bt, lens)
-    ref = paged_decode_attention_ref(q, kp, vp, bt, lens)
+    out = paged_decode_attention(q, kp, vp, bt, lens, kv_heads=K)
+    ref = paged_decode_attention_ref(q, kp, vp, bt, lens, kv_heads=K)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
+                               rtol=5e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("K", [1, 5, 8])
+@pytest.mark.parametrize("Dh", [64, 128])
+def test_paged_kernel_pool_layout_matches_ref(K, Dh):
+    """The pool row holds a position's K heads merged, padded to whole
+    128-lane rows (5 x 64 = 320 -> 384).  Decode and verify through the
+    kernel match the oracle, and the padding lanes are never read: filling
+    them with NaN changes nothing."""
+    from repro.kernels.paged_attention.ops import (
+        paged_decode_attention, paged_verify_attention, row_width)
+    from repro.kernels.paged_attention.ref import (
+        paged_decode_attention_ref, paged_verify_attention_ref)
+
+    W = row_width(K, Dh)
+    assert K * Dh <= W < K * Dh + 128 and (W % 128 == 0 or W == K * Dh)
+    B, G, S, bs, mb = 2, 3, 3, 16, 3
+    H, nb = K * G, B * mb + 1
+    rng = np.random.default_rng(K * 1000 + Dh)
+    pools = []
+    for _ in range(2):
+        pool = rng.normal(size=(nb, bs, W)).astype(np.float32)
+        pool[..., K * Dh:] = np.nan
+        pools.append(jnp.asarray(pool))
+    kp, vp = pools
+    bt = jnp.asarray(1 + rng.permutation(B * mb).reshape(B, mb), jnp.int32)
+    lens = jnp.asarray([5, mb * bs], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(B, H, Dh)), jnp.float32)
+    out = paged_decode_attention(q, kp, vp, bt, lens, kv_heads=K)
+    ref = paged_decode_attention_ref(q, kp, vp, bt, lens, kv_heads=K)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=5e-2, atol=2e-2)
+    qv = jnp.asarray(rng.normal(size=(B, S, H, Dh)), jnp.float32)
+    off = jnp.asarray([0, mb * bs - S], jnp.int32)
+    out = paged_verify_attention(qv, kp, vp, bt, off, kv_heads=K)
+    ref = paged_verify_attention_ref(qv, kp, vp, bt, off, kv_heads=K)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=5e-2, atol=2e-2)
 
 
@@ -213,12 +253,7 @@ def test_attention_decode_paged_bitwise_equals_dense(arch):
     nb = B * mb + 1
     perm = np.random.default_rng(0).permutation(np.arange(1, nb))
     bt = jnp.asarray(perm.reshape(B, mb), jnp.int32)
-    to_paged = {"k": "kp", "v": "vp", "ckv": "ckvp", "krope": "kropep"}
-    paged = {}
-    for dk, dv in dense.items():
-        pool = jnp.zeros((nb, bs) + dv.shape[2:], dv.dtype)
-        rows = dv.reshape((B * mb, bs) + dv.shape[2:])
-        paged[to_paged[dk]] = pool.at[bt.reshape(-1)].set(rows)
+    paged = _paged_from_dense(cfg, dense, bt, nb, bs)
     x = jax.random.normal(jax.random.key(2), (B, 1, cfg.d_model),
                           jnp.bfloat16)
     pos = jnp.asarray([2, 17, 30], jnp.int32)
@@ -226,6 +261,114 @@ def test_attention_decode_paged_bitwise_equals_dense(arch):
     out_p, _ = attn.attention_decode(x, p, cfg, paged, pos, block_tables=bt)
     np.testing.assert_array_equal(np.asarray(out_d, np.float32),
                                   np.asarray(out_p, np.float32))
+
+
+def _paged_from_dense(cfg, dense, bt, nb, bs):
+    """Scatter a dense cache's rows (B, T, ...) into block pools in the
+    engine's pool layout, row b's block j at physical id ``bt[b, j]``."""
+    from repro.models import attention as attn
+
+    to_paged = {"k": "kp", "v": "vp", "ckv": "ckvp", "krope": "kropep"}
+    pools = attn.init_kv_cache_paged(cfg, bt.shape[0], bt.size * bs, nb,
+                                     bs)
+    paged = {}
+    for dk, dv in dense.items():
+        pool = pools[to_paged[dk]]
+        rows = dv.reshape((bt.size, bs) + dv.shape[2:])
+        paged[to_paged[dk]] = pool.at[bt.reshape(-1)].set(
+            attn.pool_rows(pool, rows, 2))
+    return paged
+
+
+def _padded_gqa_cfg():
+    """smollm-360m's smoke model with 5 kv heads of 32: a 160-lane row,
+    stored padded to 256 lanes."""
+    import dataclasses
+    return dataclasses.replace(get_smoke_config("smollm-360m"),
+                               num_heads=15, num_kv_heads=5, head_dim=32)
+
+
+def test_attention_paged_padded_rows_bitwise_equal_dense():
+    """With padding lanes in the pool row, paged decode and speculative
+    verify still reproduce the dense ring path bit for bit: writes merge
+    and pad the new rows, the gather reads the K heads back."""
+    from repro.kernels.paged_attention.ops import row_width
+    from repro.models import attention as attn
+
+    cfg = _padded_gqa_cfg()
+    assert row_width(cfg.num_kv_heads, cfg.head_dim) == 256
+    p = attn.init_attention(jax.random.key(1), cfg)
+    B, T, bs = 3, 32, 16
+    mb = T // bs
+    nb = B * mb + 1
+    dense = {k: (jax.random.normal(jax.random.fold_in(jax.random.key(3), i),
+                                   v.shape, jnp.float32) * 0.1).astype(
+                                       v.dtype)
+             for i, (k, v) in enumerate(
+                 attn.init_kv_cache(cfg, B, T).items())}
+    perm = np.random.default_rng(0).permutation(np.arange(1, nb))
+    bt = jnp.asarray(perm.reshape(B, mb), jnp.int32)
+    paged = _paged_from_dense(cfg, dense, bt, nb, bs)
+    assert paged["kp"].shape == (nb, bs, 256)
+    x = jax.random.normal(jax.random.key(2), (B, 1, cfg.d_model),
+                          jnp.bfloat16)
+    pos = jnp.asarray([2, 17, 30], jnp.int32)
+    out_d, new_d = attn.attention_decode(x, p, cfg, dense, pos)
+    out_p, new_p = attn.attention_decode(x, p, cfg, paged, pos,
+                                         block_tables=bt)
+    np.testing.assert_array_equal(np.asarray(out_d, np.float32),
+                                  np.asarray(out_p, np.float32))
+    # the written row reads back as the dense ring's, padding left zero
+    from repro.kernels.paged_attention.ref import gather_kv
+    got = gather_kv(new_p["kp"], bt, (cfg.num_kv_heads, cfg.head_dim))
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(new_d["k"], np.float32))
+    pad = np.asarray(new_p["kp"][..., cfg.num_kv_heads * cfg.head_dim:],
+                     np.float32)
+    assert not pad.any()
+    # verify: S = 3 queries per row against the same pool
+    xs = jax.random.normal(jax.random.key(4), (B, 3, cfg.d_model),
+                           jnp.bfloat16)
+    pos = jnp.asarray([1, 9, 20], jnp.int32)
+    out_v, _ = attn.attention_verify(xs, p, cfg, paged, pos,
+                                     block_tables=bt)
+    ring = dense
+    for s in range(3):
+        out_s, ring = attn.attention_decode(xs[:, s:s + 1], p, cfg, ring,
+                                            pos + s)
+        np.testing.assert_array_equal(np.asarray(out_v[:, s], np.float32),
+                                      np.asarray(out_s[:, 0], np.float32))
+
+
+def test_handoff_keeps_host_format_on_padded_pool():
+    """A KV handoff carries each GQA row as (K, Dh), without the pool's
+    padding lanes, and importing it resumes the stream bit for bit."""
+    cfg = _padded_gqa_cfg()
+    params = _params(cfg)
+    reqs = [(0, 9, 5), (1, 20, 4)]
+    uni = ServeEngine(cfg, params, slots=2, max_len=64)
+    pf = ServeEngine(cfg, params, slots=2, max_len=64, role="prefill")
+    dc = ServeEngine(cfg, params, slots=2, max_len=64, role="decode")
+    for rid, plen, mnt in reqs:
+        uni.submit(_req(rid, plen, mnt, cfg.vocab_size))
+        pf.submit(_req(rid, plen, mnt, cfg.vocab_size))
+    uni.run()
+    pf.run()
+    K, Dh = cfg.num_kv_heads, cfg.head_dim
+    for rid, plen, mnt in reqs:
+        h = pf.done[rid].handoff
+        for leaf in h.blocks:
+            for key in ("kp", "vp"):
+                assert leaf[key].shape[1:] == (h.n_prompt_blocks,
+                                               pf.block_size, K, Dh)
+        assert h.fingerprint == dc.kv_fingerprint()
+        req = _req(rid, plen, mnt, cfg.vocab_size)
+        req.handoff = h
+        dc.submit(req)
+    dc.run()
+    for rid, _, _ in reqs:
+        assert dc.done[rid].tokens == uni.done[rid].tokens
+    assert pf.block_leaks() == 0 and dc.block_leaks() == 0
 
 
 # ---------------------------------------------------------------------------

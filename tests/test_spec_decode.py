@@ -67,19 +67,23 @@ def test_spec_ineligible_reasons():
     (1, 5, 8, 1, 32, 8, 3),               # MQA-style grouping
 ])
 def test_paged_verify_kernel_matches_ref(B, S, H, K, Dh, bs, mb):
-    from repro.kernels.paged_attention.ops import paged_verify_attention
+    from repro.kernels.paged_attention.ops import (paged_verify_attention,
+                                                   row_width)
     from repro.kernels.paged_attention.ref import paged_verify_attention_ref
 
     rng = np.random.default_rng(0)
     nb = B * mb + 1
     q = jnp.asarray(rng.normal(size=(B, S, H, Dh)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(nb, bs, K, Dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, K, Dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(nb, bs, row_width(K, Dh))),
+                     jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(nb, bs, row_width(K, Dh))),
+                     jnp.float32)
     tables = jnp.asarray(
         1 + np.arange(B * mb).reshape(B, mb), jnp.int32)
     off = jnp.asarray(rng.integers(0, mb * bs - S, size=(B,)), jnp.int32)
-    out = paged_verify_attention(q, kp, vp, tables, off, interpret=True)
-    ref = paged_verify_attention_ref(q, kp, vp, tables, off)
+    out = paged_verify_attention(q, kp, vp, tables, off, kv_heads=K,
+                                 interpret=True)
+    ref = paged_verify_attention_ref(q, kp, vp, tables, off, kv_heads=K)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=0, atol=2e-2)
 
@@ -88,19 +92,23 @@ def test_paged_verify_kernel_overflow_positions_are_safe():
     """Query positions past the table's reach (off + s >= mb*bs) must not
     crash or poison finite rows — acceptance clamps them away, but the
     kernel still computes them."""
-    from repro.kernels.paged_attention.ops import paged_verify_attention
+    from repro.kernels.paged_attention.ops import (paged_verify_attention,
+                                                   row_width)
     from repro.kernels.paged_attention.ref import paged_verify_attention_ref
 
     rng = np.random.default_rng(1)
     B, S, H, K, Dh, bs, mb = 3, 5, 4, 2, 16, 8, 4
     nb = B * mb + 1
     q = jnp.asarray(rng.normal(size=(B, S, H, Dh)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(nb, bs, K, Dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, K, Dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(nb, bs, row_width(K, Dh))),
+                     jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(nb, bs, row_width(K, Dh))),
+                     jnp.float32)
     tables = jnp.asarray(1 + np.arange(B * mb).reshape(B, mb), jnp.int32)
     off = jnp.asarray([mb * bs - 2, mb * bs - 1, mb * bs - 3], jnp.int32)
-    out = paged_verify_attention(q, kp, vp, tables, off, interpret=True)
-    ref = paged_verify_attention_ref(q, kp, vp, tables, off)
+    out = paged_verify_attention(q, kp, vp, tables, off, kv_heads=K,
+                                 interpret=True)
+    ref = paged_verify_attention_ref(q, kp, vp, tables, off, kv_heads=K)
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=0, atol=2e-2)

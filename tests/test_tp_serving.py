@@ -204,7 +204,7 @@ for name, arch, flags, kw in [
 cfg = b.get_smoke_config("starcoder2-3b")
 state = init_decode_state(cfg, 2, 64, kv="paged", num_blocks=9,
                           block_size=8)
-sh = serve_state_shardings(state, mesh)
+sh = serve_state_shardings(state, mesh, cfg)
 specs = {}
 def walk(path, node):
     if isinstance(node, dict):
@@ -219,7 +219,7 @@ walk((), sh)
 kp = [v for k, v in specs.items() if k.endswith("kp")]
 bt = [v for k, v in specs.items() if k.endswith("block_tables")]
 out["state_rules"] = {
-    "kp_head_sharded": all(MODEL_AXIS in s and s[-2] == MODEL_AXIS
+    "kp_head_sharded": all(MODEL_AXIS in s and s[-1] == MODEL_AXIS
                            for s in kp) and bool(kp),
     "tables_replicated": all(all(a is None for a in s) for s in bt),
 }
@@ -252,12 +252,13 @@ key = jax.random.key(7)
 B, nb, bs, K, G, Dh = 2, 9, 8, 2, 2, 16
 ks = jax.random.split(key, 4)
 q = jax.random.normal(ks[0], (B, K * G, Dh), jnp.bfloat16)
-kp = jax.random.normal(ks[1], (nb, bs, K, Dh), jnp.bfloat16)
-vp = jax.random.normal(ks[2], (nb, bs, K, Dh), jnp.bfloat16)
+kp = jax.random.normal(ks[1], (nb, bs, K * Dh), jnp.bfloat16)
+vp = jax.random.normal(ks[2], (nb, bs, K * Dh), jnp.bfloat16)
 tables = jnp.array([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
 cache_len = jnp.array([13, 27], jnp.int32)
-ref = paged_decode_attention(q, kp, vp, tables, cache_len)
-tp = paged_decode_attention_tp(q, kp, vp, tables, cache_len, mesh)
+ref = paged_decode_attention(q, kp, vp, tables, cache_len, kv_heads=K)
+tp = paged_decode_attention_tp(q, kp, vp, tables, cache_len, mesh,
+                               kv_heads=K)
 out["kernel_bitwise"] = bool(
     jnp.all(ref.astype(jnp.float32) == tp.astype(jnp.float32)))
 

@@ -10,6 +10,9 @@ Nothing runs: a pass says the compiler accepts the kernel, not that its
 results are right (``chip_smoke.py`` checks that on the chip).
 """
 
+import importlib.util
+import pathlib
+
 import pytest
 
 import jax
@@ -17,6 +20,8 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import get_config
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture(scope="module")
@@ -43,17 +48,19 @@ def one_chip(topo):
 
 def _paged(arch, sd, *, n_q=None):
     from repro.kernels.paged_attention.ops import (
-        paged_decode_attention, paged_verify_attention)
+        paged_decode_attention, paged_verify_attention, row_width)
     cfg = get_config(arch)
     H, K, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     B, bs, mb = 8, 16, 64                      # 8 slots x 1024 tokens
     nb = B * mb + 1
-    pool = sd((nb, bs, K, Dh), jnp.bfloat16)
+    pool = sd((nb, bs, row_width(K, Dh)), jnp.bfloat16)
     tables, rows = sd((B, mb), jnp.int32), sd((B,), jnp.int32)
     if n_q is None:
-        return (lambda *a: paged_decode_attention(*a, interpret=False),
+        return (lambda *a: paged_decode_attention(*a, kv_heads=K,
+                                                  interpret=False),
                 (sd((B, H, Dh), jnp.bfloat16), pool, pool, tables, rows))
-    return (lambda *a: paged_verify_attention(*a, interpret=False),
+    return (lambda *a: paged_verify_attention(*a, kv_heads=K,
+                                              interpret=False),
             (sd((B, n_q, H, Dh), jnp.bfloat16), pool, pool, tables, rows))
 
 
@@ -126,3 +133,24 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     fn, args = CASES[case](sd)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), case
+
+
+def test_decode_step_reads_pools_in_place(one_chip, monkeypatch):
+    """The engine's decode step for the benchmark's 32x2048 smollm-360m
+    image, compiled for a v5e, holds no copy shaped like a KV pool: the
+    pools are stored in the layout the paged kernel streams, and the step
+    writes its new cache as its output.  The step asks the backend whether
+    to interpret the kernel; this process's backend is the CPU, so the
+    test tells it the TPU's answer."""
+    import dataclasses
+    import repro.kernels.paged_attention.ops as paged_ops
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", REPO / "chip_smoke.py")
+    chip_smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(chip_smoke)
+    monkeypatch.setattr(paged_ops, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("smollm-360m"), attn_impl="pallas")
+    copies = chip_smoke.decode_step_pool_copies(
+        cfg, chip_smoke.POOL_SLOTS, chip_smoke.POOL_MAX_LEN,
+        sharding=one_chip)
+    assert copies == []
