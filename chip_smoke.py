@@ -8,9 +8,12 @@ d_ff 2560, vocab 49152, tied; random weights from ``--seed``):
 
 1. kernels — flash prefill, paged decode and paged verify run compiled
    (``interpret=False``) and are compared on the device with their
-   ``ref.py`` oracles under written tolerances; the engine's lowered
-   decode step must contain ``tpu_custom_call`` (a Pallas kernel), so a
-   kernel cannot silently have become a reference; and the decode step of
+   ``ref.py`` oracles under written tolerances; so is the dropless MoE
+   layer at granite-moe-3b-a800m widths (grouped-matmul kernel) against
+   per-token dense gating, with its share of padding rows; the engine's
+   lowered decode step must contain ``tpu_custom_call`` (a Pallas
+   kernel), so a kernel cannot silently have become a reference; and the
+   decode step of
    the 32x2048 image, compiled for this chip, must hold no ``copy`` shaped
    like a KV pool (a layer's slice or all layers), so the paged kernel
    reads the pools in the layout the device stores them in.
@@ -51,6 +54,8 @@ PROMPT_LEN = (64, 512)            # inclusive bounds
 BUDGET = (16, 64)
 
 POOL_SLOTS, POOL_MAX_LEN = 32, 2048   # the benchmark's big serve image
+
+MOE_ARCH, MOE_TOKENS = "granite-moe-3b-a800m", 2048   # one MoE layer
 
 TP_ARCH = "granite-moe-3b-a800m"
 TP_LAYERS = 16                    # of 32: f32 weights of the full depth do
@@ -210,6 +215,52 @@ def kernel_phase(cfg, seed: int, *, interpret: bool = False) -> dict:
         say(f"kernel {name}: compile {first - steady!r} s, "
             f"run {steady!r} s (smoke figures)")
     return out
+
+
+def moe_phase(seed: int) -> dict:
+    """The dropless MoE layer at granite-moe-3b-a800m widths (d_model
+    1536, 40 experts of 512, top-8) over one 2048-token prompt: the
+    grouped-matmul kernel path against per-token dense gating computed in
+    float32 on the same device, under the kernel tolerance.  The layer
+    routes on router logits at the default matmul precision (one bf16 pass
+    on a TPU), the reference at float32: tokens whose top-8 sets differ
+    between the two (near-ties at the 8th expert) are left out and
+    counted.  Prints the share of the grouped matmul's rows that pad
+    groups to whole tiles."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.configs.base import get_config
+    from repro.models import moe
+
+    cfg = get_config(MOE_ARCH)
+    k = cfg.moe.top_k
+    p = moe.init_moe(jax.random.key(seed), cfg)
+    x = jax.random.normal(jax.random.key(seed + 1), (1, MOE_TOKENS,
+                                                     cfg.d_model),
+                          jnp.bfloat16)
+    if not moe._kernel_ok():
+        raise SmokeFailure("the MoE layer would not take the Pallas kernel")
+    layer = jax.jit(lambda x, p: moe.apply_moe(x, p, cfg)[0])
+    got, first, steady = _timed(layer, x, p)
+    experts = jax.jit(lambda x, r: jnp.sort(jax.lax.top_k(
+        moe.router_probs(x, r), k)[1], axis=-1))
+    routed = experts(x, p["router"])
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda x, p: moe.apply_moe_dense(
+            x.astype(jnp.float32), p, cfg, compute=jnp.float32)[0])(x, p)
+        same = np.asarray(jnp.all(routed == experts(x, p["router"]),
+                                  axis=-1))[0]
+    say(f"kernel moe_dropless: {int((~same).sum())} of {MOE_TOKENS} tokens "
+        f"route differently at float32 (near-ties), left out")
+    err = _check_close("moe_dropless", np.asarray(got, np.float32)[0][same],
+                       np.asarray(want)[0][same])
+    rows, tile = moe.dropless_rows(MOE_TOKENS, cfg)
+    pairs = MOE_TOKENS * k
+    say(f"kernel moe_dropless: compile {first - steady!r} s, run "
+        f"{steady!r} s (smoke figures); moe_rows_run / moe_rows = "
+        f"{rows}/{pairs} = {rows / pairs!r} (tile {tile})")
+    return {"max_abs_err": err, "rows_ratio": rows / pairs}
 
 
 def decode_step_has_kernel(cfg) -> bool:
@@ -373,6 +424,7 @@ def one_chip(seed: int, meter: CompileMeter) -> None:
     cfg = img.config()
     t0 = time.monotonic()
     kernel_phase(cfg, seed)
+    moe_phase(seed)
     say(f"kernel phase: {time.monotonic() - t0!r} s")
     meter.lap("kernel phase")
     if not decode_step_has_kernel(cfg):

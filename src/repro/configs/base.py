@@ -12,6 +12,7 @@ dataclasses so they can be hashed into compile-cache keys.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Callable, Mapping
 
 # --------------------------------------------------------------------------
@@ -26,7 +27,6 @@ class MoESpec:
     num_experts: int
     top_k: int
     d_ff_expert: int          # hidden width of ONE expert
-    capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     # "tp": expert hidden dim sharded over the model axis (tokens stay put).
     # "ep": experts sharded over the model axis (tokens all-to-all).
@@ -100,6 +100,11 @@ class ArchConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     logit_softcap: float | None = None
+    # ---- published muP-style scalars (granite); 1 / None add no op ----
+    embedding_multiplier: float = 1.0   # scales the token embeddings
+    attention_multiplier: float | None = None   # score scale; None: 1/sqrt(Dh)
+    residual_multiplier: float = 1.0    # scales each residual branch
+    logits_scaling: float = 1.0         # divides the output logits
     # remat policy for the layer scan: "full" | "dots" | "none"
     remat: str = "full"
     # attention implementation: "chunked" (pure-JAX flash-style, default),
@@ -111,8 +116,6 @@ class ArchConfig:
     loss_chunk: int = 1024
     # SSM mixer implementation: "chunked" (pure-JAX SSD) | "pallas"
     ssm_impl: str = "chunked"
-    # MoE expert matmul: "einsum" (capacity buckets) | "gmm" (Pallas kernel)
-    moe_impl: str = "einsum"
     # norm implementation: "jnp" | "pallas" (fused kernel)
     norm_impl: str = "jnp"
 
@@ -121,6 +124,17 @@ class ArchConfig:
             raise ValueError(f"unknown family {self.family!r}")
         if self.head_dim == 0 and self.num_heads:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        if self.attention_multiplier is not None and self.mla is not None:
+            raise ValueError("attention_multiplier is applied to GQA heads "
+                             "only; MLA keeps 1/sqrt(qk_head_dim)")
+
+    @property
+    def q_scale(self) -> float:
+        """Factor on q that turns the attend paths' 1/sqrt(Dh) score scale
+        into ``attention_multiplier`` (1.0 when it is unset)."""
+        if self.attention_multiplier is None:
+            return 1.0
+        return self.attention_multiplier * math.sqrt(self.head_dim)
 
     # ---- derived quantities -------------------------------------------------
 

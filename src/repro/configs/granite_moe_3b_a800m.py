@@ -1,7 +1,9 @@
 """granite-moe-3b-a800m — fine-grained MoE, 40 experts top-8.
 
 [hf:ibm-granite/granite-3.0-3b-a800m-base; hf]
-32L d_model=1536 24H (GQA kv=8) d_ff=512-per-expert vocab=49155, MoE every layer.
+32L d_model=1536 24H (GQA kv=8) d_ff=512-per-expert vocab=49155, MoE every layer,
+dropless routing; published scalars: embedding x12, attention scores x1/64,
+residual branches x0.22, logits /6; RMSNorm eps 1e-6.
 """
 
 from repro.configs.base import ArchConfig, MoESpec, register, register_smoke
@@ -25,7 +27,12 @@ def config() -> ArchConfig:
         moe=MoESpec(num_experts=40, top_k=8, d_ff_expert=512),
         moe_period=1,
         norm="rmsnorm",
+        norm_eps=1e-6,
         tie_embeddings=True,
+        embedding_multiplier=12.0,
+        attention_multiplier=0.015625,
+        residual_multiplier=0.22,
+        logits_scaling=6.0,
     )
 
 
@@ -42,6 +49,11 @@ def smoke() -> ArchConfig:
         vocab_size=512,
         moe=MoESpec(num_experts=8, top_k=4, d_ff_expert=32),
         moe_period=1,
+        norm_eps=1e-6,
         tie_embeddings=True,
+        embedding_multiplier=12.0,
+        attention_multiplier=0.015625,
+        residual_multiplier=0.22,
+        logits_scaling=6.0,
         attn_chunk=64,
     )

@@ -177,7 +177,7 @@ _SERVE_STAT_KEYS = (
     "ttft_p50_s", "ttft_p99_s",
     "kv", "kv_memory_utilization", "kv_peak_live_tokens",
     "kv_capacity_tokens", "prefix_hit_rate", "prefill_chunks",
-    "blocked_admissions",
+    "blocked_admissions", "moe_rows", "moe_rows_run",
     "spec", "spec_fallback_reason", "acceptance_rate", "tokens_per_step",
     "mesh_shape", "mesh_devices", "slots",
     "kv_pool_bytes", "kv_pool_bytes_per_device",

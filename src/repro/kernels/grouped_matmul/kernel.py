@@ -22,6 +22,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+# the kernel's name in HLO and in the device trace
+TRACE_NAME = "grouped_matmul"
+
+
 def _gmm_kernel(gids_ref, x_ref, w_ref, y_ref):
     x = x_ref[...]                                            # (block_m, D)
     w = w_ref[0]                                              # (D, block_n)
@@ -58,4 +62,5 @@ def grouped_matmul_kernel(x, w, tile_expert_ids, *, block_m=128, block_n=128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, F), jnp.float32),
         interpret=interpret,
+        name=TRACE_NAME,
     )(tile_expert_ids.astype(jnp.int32), x, w)

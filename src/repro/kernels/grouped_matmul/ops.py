@@ -2,8 +2,9 @@
 
 `grouped_matmul` takes the ragged layout (rows sorted by expert +
 group_sizes) and builds the per-tile expert map.  Group boundaries must be
-block_m-aligned (the dense-padding contract); `pad_group_sizes` and the
-capacity-bucket helper below produce aligned layouts.
+block_m-aligned (the dense-padding contract); `pad_group_sizes` produces
+aligned layouts.  The model's dropless MoE layer (``models/moe.py``)
+builds its tile map itself and calls the kernel directly.
 """
 
 from __future__ import annotations
@@ -46,14 +47,3 @@ def grouped_matmul(x, w, group_sizes, *, block_m=128, block_n=128,
     return grouped_matmul_kernel(x, w, tile_ids, block_m=block_m,
                                  block_n=block_n, interpret=interpret)
 
-
-def bucket_matmul(buckets, w, *, block_m=128, block_n=128, interpret=None):
-    """Capacity-bucket layout (models/moe.py): buckets (E,C,D) -> (E,C,F).
-    Equal group sizes C; requires C % block_m == 0 or C <= block_m."""
-    E, C, D = buckets.shape
-    bm = min(block_m, C)
-    x = buckets.reshape(E * C, D)
-    sizes = jnp.full((E,), C, jnp.int32)
-    y = grouped_matmul(x, w, sizes, block_m=bm, block_n=block_n,
-                       interpret=interpret)
-    return y.reshape(E, C, w.shape[2])

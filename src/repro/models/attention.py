@@ -259,6 +259,14 @@ def decode_attend(q, k_cache, v_cache, cache_len, *, window=None):
 # Full layer forward (projection + rope + attend + out-proj)
 # ==========================================================================
 
+def scale_q(q, cfg):
+    """q times ``cfg.q_scale``, so that every attend path's 1/sqrt(Dh)
+    score scale becomes the config's attention multiplier.  No op where
+    the multiplier is unset (the step compiles as without it)."""
+    s = cfg.q_scale
+    return q if s == 1.0 else q * jnp.asarray(s, q.dtype)
+
+
 def attention_forward(x, p, cfg, *, rope_cos, rope_sin, causal=True,
                       window=None, kv=None, compute=jnp.bfloat16):
     """Self- (kv=None) or cross- (kv=(k_in,)) attention over a full sequence.
@@ -278,6 +286,7 @@ def attention_forward(x, p, cfg, *, rope_cos, rope_sin, causal=True,
     if rope_cos is not None:
         q = apply_rope(q, rope_cos, rope_sin)
         k = apply_rope(k, rope_cos, rope_sin)
+    q = scale_q(q, cfg)
     out = constrain(attend(q, k, v, cfg, causal=causal, window=window),
                     "b.m.")
     return jnp.einsum("bshk,hkd->bsd", constrain_replicated(out), p["wo"].astype(compute))
@@ -316,6 +325,7 @@ def attention_prefill(x, p, cfg, rope, cache, *, window=None,
     if rope[0] is not None:
         q = apply_rope(q, rope[0], rope[1])
         k = apply_rope(k, rope[0], rope[1])
+    q = scale_q(q, cfg)
     out = constrain(attend(q, k, v, cfg, causal=True, window=window), "b.m.")
     out = jnp.einsum("bshk,hkd->bsd", constrain_replicated(out), p["wo"].astype(compute))
     return out, _ring_write_full(k, v, cache, window)
@@ -447,7 +457,7 @@ def attention_decode(x, p, cfg, cache, pos, *, rope_theta=None,
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(compute))
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(compute))
     cos, sin = rope_table(pos[:, None], cfg.head_dim, theta)   # (B,1,dim/2)
-    q = apply_rope(q, cos, sin)
+    q = scale_q(apply_rope(q, cos, sin), cfg)
     k = apply_rope(k, cos, sin)
     if "kp" in cache:                       # paged block pool
         k_pool = _paged_write_rows(cache["kp"], k, block_tables, pos)
@@ -536,7 +546,7 @@ def attention_verify(x, p, cfg, cache, pos, *, block_tables,
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(compute))
     positions = pos[:, None] + jnp.arange(S)[None]             # (B, S)
     cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
+    q = scale_q(apply_rope(q, cos, sin), cfg)
     k = apply_rope(k, cos, sin)
     k_pool = _paged_write_seq(cache["kp"], k, block_tables, pos)
     v_pool = _paged_write_seq(cache["vp"], v, block_tables, pos)
@@ -833,7 +843,7 @@ def attention_prefill_chunk(x, p, cfg, cache, table_row, slot, q_offset,
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(compute))
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(compute))
     cos, sin = rope_table(positions[None], cfg.head_dim, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
+    q = scale_q(apply_rope(q, cos, sin), cfg)
     k = apply_rope(k, cos, sin)
     if "kp" in cache:                        # full attention: paged pool
         k_pool = _paged_write_chunk(cache["kp"], k[0], table_row, positions)

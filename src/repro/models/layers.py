@@ -132,9 +132,12 @@ def embed_lookup(tokens, table, compute_dtype=jnp.bfloat16):
     return jnp.take(table, tokens, axis=0).astype(compute_dtype)
 
 
-def lm_logits(x, head, softcap: float | None = None):
-    """x: (B,S,D) compute dtype; head: (D,V).  Returns f32 logits."""
+def lm_logits(x, head, softcap: float | None = None, scaling: float = 1.0):
+    """x: (B,S,D) compute dtype; head: (D,V).  Returns f32 logits, divided
+    by ``scaling`` (the published logits scaling; no op at 1)."""
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype)).astype(jnp.float32)
+    if scaling != 1.0:
+        logits = logits / scaling
     if softcap is not None:
         logits = softcap * jnp.tanh(logits / softcap)
     # serve TP: head is vocab-sharded (column-parallel); gather so the
@@ -154,7 +157,7 @@ def softmax_cross_entropy(logits, targets, mask=None):
 
 
 def softmax_cross_entropy_fused(h, head, targets, *, softcap=None, mask=None,
-                                chunk: int = 1024):
+                                chunk: int = 1024, scaling: float = 1.0):
     """Mean CE of ``logits = h @ head`` WITHOUT materializing (B,S,V).
 
     The full logits tensor is the single largest intermediate of an LM train
@@ -169,7 +172,8 @@ def softmax_cross_entropy_fused(h, head, targets, *, softcap=None, mask=None,
     """
     B, S, D = h.shape
     if S <= chunk:
-        return softmax_cross_entropy(lm_logits(h, head, softcap), targets, mask)
+        return softmax_cross_entropy(lm_logits(h, head, softcap, scaling),
+                                     targets, mask)
     if mask is None:
         mask = jnp.ones((B, S), jnp.float32)
     pad = (-S) % chunk
@@ -188,7 +192,7 @@ def softmax_cross_entropy_fused(h, head, targets, *, softcap=None, mask=None,
         tot, cnt = carry
         hb, tb, mb = inp
         hb = constrain(hb, "b..")
-        logits = lm_logits(hb, head, softcap)            # (B,c,V) temporary
+        logits = lm_logits(hb, head, softcap, scaling)   # (B,c,V) temporary
         logits = constrain(logits, "b.m")
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, tb[..., None], axis=-1)[..., 0]

@@ -71,6 +71,31 @@ def _rope_for(cfg, S):
     return rope_table(jnp.arange(S), dim, cfg.rope_theta)
 
 
+# The published scalars (``embedding_multiplier``, ``residual_multiplier``,
+# ``logits_scaling``; the attention multiplier lives in `attention.scale_q`)
+# are skipped in Python where they are 1, so a model without them traces
+# exactly the ops it did before they existed.
+
+def _embed(params, cfg, tokens, compute):
+    """Token embeddings in ``compute``, times the embedding multiplier."""
+    if cfg.embedding_multiplier == 1.0:
+        return embed_lookup(tokens, params["embed"], compute)
+    x = jnp.take(params["embed"], tokens, axis=0)
+    return (x * cfg.embedding_multiplier).astype(compute)
+
+
+def _residual(x, h, cfg):
+    """``x + h``, the branch ``h`` scaled by the residual multiplier."""
+    if cfg.residual_multiplier == 1.0:
+        return x + h
+    return x + h * cfg.residual_multiplier
+
+
+def _logits(x, params, cfg):
+    return lm_logits(x, head_matrix(params, cfg), cfg.logit_softcap,
+                     cfg.logits_scaling)
+
+
 # --------------------------------------------------------------------------
 # Init
 # --------------------------------------------------------------------------
@@ -133,14 +158,14 @@ def _apply_slot(x, p, cfg, slot, rope, compute):
             causal=True, window=cfg.sliding_window, compute=compute)
     else:
         h = ssm_mod.ssm_forward(h, p["mixer"], cfg, compute=compute)
-    x = x + h
+    x = _residual(x, h, cfg)
     if slot["ffn"] != "none":
         h = apply_norm(x, p["ffn_norm"], cfg)
         if slot["ffn"] == "dense":
             h = apply_mlp(h, p["ffn"], cfg, compute)
         else:
             h, aux = moe_mod.apply_moe(h, p["ffn"], cfg, compute)
-        x = x + h
+        x = _residual(x, h, cfg)
     return x, aux
 
 
@@ -174,7 +199,7 @@ def lm_loss(params, cfg, tokens, targets, *, extra_embeds=None,
             loss_mask=None, compute=jnp.bfloat16):
     """Next-token CE loss.  extra_embeds (B,F,D) are prepended (VLM/audio
     stub frontends); the loss covers token positions only."""
-    x = embed_lookup(tokens, params["embed"], compute)
+    x = _embed(params, cfg, tokens, compute)
     n_extra = 0
     if extra_embeds is not None:
         n_extra = extra_embeds.shape[1]
@@ -183,7 +208,8 @@ def lm_loss(params, cfg, tokens, targets, *, extra_embeds=None,
     h = h[:, n_extra:]
     ce = softmax_cross_entropy_fused(
         h, head_matrix(params, cfg), targets,
-        softcap=cfg.logit_softcap, mask=loss_mask, chunk=cfg.loss_chunk)
+        softcap=cfg.logit_softcap, mask=loss_mask, chunk=cfg.loss_chunk,
+        scaling=cfg.logits_scaling)
     return ce + aux, {"ce": ce, "aux": aux}
 
 
@@ -240,14 +266,14 @@ def _slot_prefill(x, p, cfg, slot, rope, old_cache, compute):
     else:
         out, nc = ssm_mod.ssm_forward_with_cache(h, p["mixer"], cfg,
                                                  compute=compute)
-    x = x + out
+    x = _residual(x, out, cfg)
     if slot["ffn"] != "none":
         h = apply_norm(x, p["ffn_norm"], cfg)
         if slot["ffn"] == "dense":
             h = apply_mlp(h, p["ffn"], cfg, compute)
         else:
             h, _ = moe_mod.apply_moe(h, p["ffn"], cfg, compute)
-        x = x + h
+        x = _residual(x, h, cfg)
     return x, nc
 
 
@@ -255,7 +281,7 @@ def lm_prefill(params, cfg, tokens, cache, *, extra_embeds=None,
                compute=jnp.bfloat16):
     """Full-sequence prefill: returns (last-position logits, filled cache)."""
     slots = layer_slots(cfg)
-    x = embed_lookup(tokens, params["embed"], compute)
+    x = _embed(params, cfg, tokens, compute)
     if extra_embeds is not None:
         x = jnp.concatenate([extra_embeds.astype(compute), x], axis=1)
     rope = _rope_for(cfg, x.shape[1])
@@ -273,7 +299,7 @@ def lm_prefill(params, cfg, tokens, cache, *, extra_embeds=None,
     x, new_cache = jax.lax.scan(_remat(group_body, cfg), x,
                                 (params["layers"], cache))
     x = apply_norm(x, params["final_norm"], cfg)
-    logits = lm_logits(x[:, -1:], head_matrix(params, cfg), cfg.logit_softcap)
+    logits = _logits(x[:, -1:], params, cfg)
     return logits, new_cache
 
 
@@ -285,7 +311,7 @@ def lm_decode(params, cfg, token, cache, pos, *, block_tables=None,
     cache leaves; one table serves every layer (all pools share physical
     block ids).  Returns (logits (B,1,V), new cache)."""
     slots = layer_slots(cfg)
-    x = embed_lookup(token, params["embed"], compute)
+    x = _embed(params, cfg, token, compute)
 
     def group_body(x, inp):
         gparams, gcache = inp
@@ -303,19 +329,19 @@ def lm_decode(params, cfg, token, cache, pos, *, block_tables=None,
                 h, nc = ssm_mod.ssm_decode(h, p["mixer"], cfg, gcache[i],
                                            compute=compute)
             new_gcache.append(nc)
-            x = x + h
+            x = _residual(x, h, cfg)
             if slot["ffn"] != "none":
                 h = apply_norm(x, p["ffn_norm"], cfg)
                 if slot["ffn"] == "dense":
                     h = apply_mlp(h, p["ffn"], cfg, compute)
                 else:
                     h, _ = moe_mod.apply_moe_dense(h, p["ffn"], cfg, compute)
-                x = x + h
+                x = _residual(x, h, cfg)
         return x, new_gcache
 
     x, new_cache = jax.lax.scan(group_body, x, (params["layers"], cache))
     x = apply_norm(x, params["final_norm"], cfg)
-    logits = lm_logits(x, head_matrix(params, cfg), cfg.logit_softcap)
+    logits = _logits(x, params, cfg)
     return logits, new_cache
 
 
@@ -333,7 +359,7 @@ def lm_verify(params, cfg, tokens, cache, pos, *, block_tables=None,
     state-rollback path (the engine falls back to spec="off" for them).
     Returns (logits (B,S,V), new cache)."""
     slots = layer_slots(cfg)
-    x = embed_lookup(tokens, params["embed"], compute)
+    x = _embed(params, cfg, tokens, compute)
 
     def group_body(x, inp):
         gparams, gcache = inp
@@ -351,19 +377,19 @@ def lm_verify(params, cfg, tokens, cache, pos, *, block_tables=None,
                 h, p["mixer"], cfg, gcache[i], pos,
                 block_tables=block_tables, compute=compute)
             new_gcache.append(nc)
-            x = x + h
+            x = _residual(x, h, cfg)
             if slot["ffn"] != "none":
                 h = apply_norm(x, p["ffn_norm"], cfg)
                 if slot["ffn"] == "dense":
                     h = apply_mlp(h, p["ffn"], cfg, compute)
                 else:
                     h, _ = moe_mod.apply_moe_dense(h, p["ffn"], cfg, compute)
-                x = x + h
+                x = _residual(x, h, cfg)
         return x, new_gcache
 
     x, new_cache = jax.lax.scan(group_body, x, (params["layers"], cache))
     x = apply_norm(x, params["final_norm"], cfg)
-    logits = lm_logits(x, head_matrix(params, cfg), cfg.logit_softcap)
+    logits = _logits(x, params, cfg)
     return logits, new_cache
 
 
@@ -377,7 +403,7 @@ def lm_prefill_chunk(params, cfg, tokens, cache, table_row, slot,
     other rows keep decoding bit-identically in between chunks.  Returns
     (last-position logits (1,V), new cache)."""
     slots = layer_slots(cfg)
-    x = embed_lookup(tokens, params["embed"], compute)
+    x = _embed(params, cfg, tokens, compute)
 
     def group_body(x, inp):
         gparams, gcache = inp
@@ -394,17 +420,17 @@ def lm_prefill_chunk(params, cfg, tokens, cache, table_row, slot,
                 h, nc = ssm_mod.ssm_prefill_chunk_row(
                     h, p["mixer"], cfg, gcache[i], slot, compute=compute)
             new_gcache.append(nc)
-            x = x + h
+            x = _residual(x, h, cfg)
             if slot_s["ffn"] != "none":
                 h = apply_norm(x, p["ffn_norm"], cfg)
                 if slot_s["ffn"] == "dense":
                     h = apply_mlp(h, p["ffn"], cfg, compute)
                 else:
                     h, _ = moe_mod.apply_moe_dense(h, p["ffn"], cfg, compute)
-                x = x + h
+                x = _residual(x, h, cfg)
         return x, new_gcache
 
     x, new_cache = jax.lax.scan(group_body, x, (params["layers"], cache))
     x = apply_norm(x, params["final_norm"], cfg)
-    logits = lm_logits(x[:, -1:], head_matrix(params, cfg), cfg.logit_softcap)
+    logits = _logits(x[:, -1:], params, cfg)
     return logits[:, 0], new_cache

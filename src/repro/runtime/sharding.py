@@ -432,6 +432,13 @@ def activation_sharding(mesh: Mesh, layout: str = "2d"):
         _ACT.ctx = prev
 
 
+def layout_mesh() -> Mesh | None:
+    """The mesh of the enclosing :func:`activation_sharding` context, of
+    any layout, or None.  Read at trace time."""
+    ctx = getattr(_ACT, "ctx", None)
+    return None if ctx is None else ctx[0]
+
+
 def active_serve_mesh() -> Mesh | None:
     """The mesh of the enclosing "serve" :func:`activation_sharding`
     context, or None.  Read at TRACE time — model code uses it to pick

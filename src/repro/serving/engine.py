@@ -83,6 +83,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.models.api import build_model, init_decode_state
 from repro.models.attention import pool_rows
+from repro.models.moe import dropless_rows
 from repro.serving.blockpool import BlockAllocator, KVHandoff, PrefixCache
 
 
@@ -533,6 +534,12 @@ class ServeEngine:
         self.spec_drafted = 0          # draft proposals scored by verify
         self.spec_accepted = 0         # of those, committed to requests
         self.tokens_emitted = 0        # total committed tokens (all modes)
+        # dropless MoE dispatch of the one-shot admission prefills, counted
+        # from static shapes (no sync): token-expert pairs routed, and the
+        # rows the grouped matmul ran, tile padding included
+        self._moe_layers = len(cfg.moe_layer_indices())
+        self.moe_rows = 0
+        self.moe_rows_run = 0
 
         # one compiled decode step for the whole engine lifetime; budget
         # and active are donated every step, the decode state is not
@@ -763,6 +770,10 @@ class ServeEngine:
         with TraceAnnotation("repro.engine.admit", rid=req.rid):
             logits, cache = self._prefill(
                 self.params, {"tokens": jnp.asarray(padded[None])})
+            if self._moe_layers:
+                self.moe_rows += self._moe_layers * plen * self.cfg.moe.top_k
+                self.moe_rows_run += (self._moe_layers
+                                      * dropless_rows(plen, self.cfg)[0])
             nxt = int(jnp.argmax(logits[0, -1]))              # admission-time
             if self.kv == "paged":
                 self.state = _install_slot_paged(
@@ -1435,6 +1446,8 @@ class ServeEngine:
                                 if self.prompt_tokens_total else 0.0),
             "prefill_chunks": self.prefill_chunks,
             "blocked_admissions": self.blocked_admissions,
+            "moe_rows": self.moe_rows,
+            "moe_rows_run": self.moe_rows_run,
             # speculative decoding
             "spec": self.spec,
             "spec_k": self.spec_k if self.spec != "off" else 0,
@@ -1470,6 +1483,8 @@ class ServeEngine:
         self.spec_drafted = 0
         self.spec_accepted = 0
         self.tokens_emitted = 0
+        self.moe_rows = 0
+        self.moe_rows_run = 0
         if self.prefix is not None:
             self.prefix.lookups = 0
             self.prefix.hits = 0

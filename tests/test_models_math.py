@@ -176,17 +176,61 @@ def test_ssm_prefill_state_matches_decode():
 # ---------------------------------------------------------------------------
 
 def test_moe_capacity_dispatch_matches_dense():
-    """With ample capacity, bucketed dispatch == dense all-experts gating."""
+    """The dropless dispatch equals dense all-experts gating: every token
+    reaches all of its top-k experts, with no capacity factor to raise."""
     cfg = get_smoke_config("mixtral-8x7b")
-    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
-        cfg.moe, capacity_factor=8.0))
     p = moe_mod.init_moe(jax.random.fold_in(KEY, 37), cfg)
     x = _r((2, 16, cfg.d_model), 38, scale=0.5, dtype=jnp.bfloat16)
-    y_bucket, _ = moe_mod.apply_moe(x, p, cfg)
+    y_sorted, _ = moe_mod.apply_moe(x, p, cfg)
     y_dense, _ = moe_mod.apply_moe_dense(x, p, cfg)
-    np.testing.assert_allclose(np.asarray(y_bucket, np.float32),
+    np.testing.assert_allclose(np.asarray(y_sorted, np.float32),
                                np.asarray(y_dense, np.float32),
                                rtol=0.1, atol=0.05)
+
+
+def test_moe_skewed_router_drops_no_token():
+    """A router that sends every token to the same top-k experts loads
+    each of them with all S tokens, far past the old 1.25x capacity
+    (S*k/E*1.25 = 80 of 128 here): the dropless layer still equals
+    per-token dense gating, where a capacity layer would have zeroed the
+    pairs past its capacity."""
+    cfg = get_smoke_config("granite-moe-3b-a800m")
+    m = cfg.moe
+    S = 128
+    p = moe_mod.init_moe(jax.random.fold_in(KEY, 41), cfg)
+    p = {**p, "router": jnp.zeros_like(p["router"]).at[:, :m.top_k].set(
+        jnp.linspace(4.0, 1.0, m.top_k))}
+    x = jnp.abs(_r((1, S, cfg.d_model), 42, scale=0.5)).astype(jnp.bfloat16)
+    probs = moe_mod.router_probs(x, p["router"])
+    _, idx = jax.lax.top_k(probs, m.top_k)
+    assert set(np.unique(np.asarray(idx)).tolist()) == set(range(m.top_k))
+    assert S > S * m.top_k / m.num_experts * 1.25
+    y, _ = moe_mod.apply_moe(x, p, cfg)
+    y_dense, _ = moe_mod.apply_moe_dense(x, p, cfg)
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               np.asarray(y_dense, np.float32),
+                               rtol=0.05, atol=0.02)
+    assert float(jnp.min(jnp.linalg.norm(y.astype(jnp.float32), axis=-1))) > 0
+
+
+def test_moe_kernel_gradient_matches_jnp_product(monkeypatch):
+    """The Pallas grouped matmul (interpreted here) has a backward of its
+    own: gradients through it equal those through the jnp product."""
+    cfg = get_smoke_config("granite-moe-3b-a800m")
+    p = moe_mod.init_moe(jax.random.fold_in(KEY, 43), cfg)
+    x = _r((2, 16, cfg.d_model), 44, scale=0.5, dtype=jnp.bfloat16)
+
+    def loss(p, x):
+        return jnp.sum(moe_mod.apply_moe(x, p, cfg)[0].astype(jnp.float32)
+                       ** 2)
+
+    want = jax.grad(loss, argnums=(0, 1))(p, x)
+    monkeypatch.setattr(moe_mod, "_kernel_ok", lambda: True)
+    got = jax.grad(loss, argnums=(0, 1))(p, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=0.02, atol=0.01)
 
 
 def test_moe_aux_loss_uniform_router_is_one():
@@ -205,7 +249,17 @@ def test_moe_aux_loss_uniform_router_is_one():
 @settings(max_examples=10, deadline=None)
 @given(s=st.integers(8, 64))
 def test_moe_capacity_bounds(s):
+    """The dropless buffer holds every pair whatever the routing:
+    S*k <= rows <= S*k + E*(tile-1), in whole tiles, and the placement
+    fills each expert's tiles with its own pairs only."""
     cfg = get_smoke_config("mixtral-8x7b")
-    c = moe_mod._capacity(cfg, s)
-    assert 1 <= c <= s
-    assert c % 8 == 0 or c == s
+    E, k = cfg.moe.num_experts, cfg.moe.top_k
+    rows, tile = moe_mod.dropless_rows(s, cfg)
+    assert s * k <= rows <= s * k + E * (tile - 1)
+    assert rows % tile == 0 and tile % 16 == 0
+    idx = jax.random.randint(jax.random.fold_in(KEY, s), (s, k), 0, E)
+    dest, tile_ids = moe_mod._sort_pairs(idx, E, rows, tile)
+    dest = np.asarray(dest)
+    assert len(set(dest.tolist())) == s * k and dest.max() < rows
+    assert np.array_equal(np.asarray(tile_ids)[dest // tile],
+                          np.asarray(idx).reshape(-1))

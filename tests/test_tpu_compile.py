@@ -135,6 +135,27 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     assert "tpu_custom_call" in compiled.as_text(), case
 
 
+def test_dropless_moe_layer_compiles_for_v5e(one_chip, monkeypatch):
+    """The dropless MoE layer at granite-moe-3b-a800m widths over one
+    2048-token prompt compiles for a v5e with the grouped-matmul kernel
+    in it.  The layer asks the backend whether to take the kernel and
+    whether to interpret it; this process's backend is the CPU, so the
+    test tells it the TPU's answer."""
+    from repro.models import moe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("granite-moe-3b-a800m")
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+    p = jax.tree.map(lambda a: sd(a.shape, a.dtype), jax.eval_shape(
+        lambda: moe.init_moe(jax.random.key(0), cfg)))
+    x = sd((1, 2048, cfg.d_model), jnp.bfloat16)
+    compiled = jax.jit(lambda x, p: moe.apply_moe(x, p, cfg)[0]).lower(
+        x, p).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3       # up, gate and down
+    assert "%grouped_matmul" in text
+
+
 def test_decode_step_reads_pools_in_place(one_chip, monkeypatch):
     """The engine's decode step for the benchmark's 32x2048 smollm-360m
     image, compiled for a v5e, holds no copy shaped like a KV pool: the
