@@ -12,11 +12,14 @@ d_ff 2560, vocab 49152, tied; random weights from ``--seed``):
    layer at granite-moe-3b-a800m widths (grouped-matmul kernel) against
    per-token dense gating, with its share of padding rows; the engine's
    lowered decode step must contain ``tpu_custom_call`` (a Pallas
-   kernel), so a kernel cannot silently have become a reference; and the
+   kernel), so a kernel cannot silently have become a reference; the
    decode step of
    the 32x2048 image, compiled for this chip, must hold no ``copy`` shaped
    like a KV pool (a layer's slice or all layers), so the paged kernel
-   reads the pools in the layout the device stores them in.
+   reads the pools in the layout the device stores them in; and the staged
+   weights init a bind dispatches must match the eager init (layers
+   bitwise, the rest within one ulp), and at granite-moe-3b-a800m's 16
+   layers hold at most ``INIT_TEMP_LIMIT`` bytes of temp.
 2. pilot — ``ClusterSim`` -> ``TaskRepo.submit(PayloadImage(...,
    smoke=False, flags=(("attn_impl", "pallas"),)))`` -> pilot ->
    ``run_wrapper`` -> ``ServeEngine`` (paged KV, Pallas prefill + paged
@@ -56,6 +59,11 @@ BUDGET = (16, 64)
 POOL_SLOTS, POOL_MAX_LEN = 32, 2048   # the benchmark's big serve image
 
 MOE_ARCH, MOE_TOKENS = "granite-moe-3b-a800m", 2048   # one MoE layer
+
+# the staged weights init of granite-moe-3b-a800m at the benchmark's 16
+# layers, compiled for this chip: most temp bytes it may hold (it makes one
+# layer group at a time; a vmap over the layers needs 10.7 GB on the CPU)
+INIT_TEMP_LIMIT = 2.5e9
 
 TP_ARCH = "granite-moe-3b-a800m"
 TP_LAYERS = 16                    # of 32: f32 weights of the full depth do
@@ -263,6 +271,50 @@ def moe_phase(seed: int) -> dict:
     return {"max_abs_err": err, "rows_ratio": rows / pairs}
 
 
+def init_phase(cfg, seed: int) -> dict:
+    """The staged weights init a serve image dispatches on each bind
+    (``jax.jit(bundle.init)``) at ``cfg``'s widths, against the eager
+    ``bundle.init`` on the device: every layer leaf bitwise, the rest
+    within one float32 ulp (XLA folds the embedding's scale).  Then the
+    same init for granite-moe-3b-a800m at the benchmark's 16 layers is
+    compiled for this chip, and its temp bytes are held to
+    ``INIT_TEMP_LIMIT``."""
+    import dataclasses
+    import jax
+    import jax.numpy as jnp
+    from repro.configs.base import get_config
+    from repro.models.api import build_model
+
+    bundle = build_model(cfg)
+    key = jax.random.key(seed)
+    staged, first, steady = _timed(jax.jit(bundle.init), key)
+    eager, eager_first, eager_steady = _timed(bundle.init, key)
+    for name in staged:
+        same = [bool(jnp.array_equal(g, w)) if name == "layers" else bool(
+                    jnp.all(jnp.abs(g - w) <= jnp.nextafter(
+                        jnp.abs(w), jnp.inf) - jnp.abs(w)))
+                for g, w in zip(jax.tree.leaves(staged[name]),
+                                jax.tree.leaves(eager[name]))]
+        say(f"init {name}: {sum(same)} of {len(same)} leaves "
+            f"{'bitwise' if name == 'layers' else 'within 1 ulp'} of the "
+            f"eager init")
+        if not all(same):
+            raise SmokeFailure(f"the staged init's {name} departs from the "
+                               f"eager init")
+    del staged, eager
+    say(f"init {cfg.name}: staged compile {first - steady!r} s, run "
+        f"{steady!r} s; eager first {eager_first!r} s, then "
+        f"{eager_steady!r} s (smoke figures, each to block_until_ready)")
+    granite = dataclasses.replace(get_config(TP_ARCH), num_layers=TP_LAYERS)
+    temp = jax.jit(build_model(granite).init).lower(
+        key).compile().memory_analysis().temp_size_in_bytes
+    say(f"init {TP_ARCH} ({TP_LAYERS} layers) compiled: temp {temp} bytes "
+        f"(limit {INIT_TEMP_LIMIT:.3g})")
+    if temp > INIT_TEMP_LIMIT:
+        raise SmokeFailure(f"the staged init holds {temp} bytes of temp")
+    return {"temp_bytes": temp}
+
+
 def decode_step_has_kernel(cfg) -> bool:
     """Lower the engine's decode step for ``cfg`` on abstract shapes and
     look for the Pallas custom call in its HLO."""
@@ -425,6 +477,7 @@ def one_chip(seed: int, meter: CompileMeter) -> None:
     t0 = time.monotonic()
     kernel_phase(cfg, seed)
     moe_phase(seed)
+    init_phase(cfg, seed)
     say(f"kernel phase: {time.monotonic() - t0!r} s")
     meter.lap("kernel phase")
     if not decode_step_has_kernel(cfg):

@@ -91,7 +91,9 @@ class Executable:
     """A pulled image: compiled function + input builders."""
     image: PayloadImage
     fn: Any                           # jitted/compiled callable
-    make_inputs: Any                  # (key) -> concrete input pytree
+    # (key) -> concrete input pytree; the serve, prefill and decode kinds
+    # take ``telemetry=`` too and record ``weights_compiles`` in it
+    make_inputs: Any
     compile_seconds: float
     cached: bool = False
     # force the lazy XLA compile now (one representative invocation);
@@ -200,6 +202,18 @@ class ExecutableRegistry:
         cfg = image.config()
         shape = image.shape_spec()
         bundle = build_model(cfg)
+        # weights from the seed for the prefill, decode and serve kinds:
+        # one staged program, compiled by warm() (or the first bind) and
+        # dispatched once by every bind, which still gets fresh buffers
+        # from its own key
+        init = jax.jit(bundle.init)
+
+        def weights(key, telemetry=None):
+            n = init._cache_size()
+            params = init(key)
+            if telemetry is not None:     # 0 on every bind after warm()
+                telemetry["weights_compiles"] = init._cache_size() - n
+            return params
 
         warm = None
         if image.mode == "train":
@@ -224,8 +238,8 @@ class ExecutableRegistry:
             step = make_prefill_step(cfg)
             fn = jax.jit(step)
 
-            def make_inputs(key):
-                params = bundle.init(key)
+            def make_inputs(key, telemetry=None):
+                params = weights(key, telemetry)
                 batch = _concrete_batch(cfg, shape, key, with_targets=False)
                 return params, batch
 
@@ -338,8 +352,7 @@ class ExecutableRegistry:
                                    chunk_fn=chunk_fn if shared else None,
                                    mesh=mesh, **kw)
 
-            def make_inputs(key):
-                return bundle.init(key)
+            make_inputs = weights
 
             def warm():
                 # build a throwaway engine THROUGH the factory so the
@@ -349,7 +362,7 @@ class ExecutableRegistry:
                 # caches production engines hit.  Specs that override
                 # engine geometry (num_blocks/block_size/prefill_chunk)
                 # trade this prewarm for a first-tick compile.
-                params = bundle.init(jax.random.key(0))
+                params = weights(jax.random.key(0))
                 eng = fn(params, prefill="chunked")
                 eng.warm_admission()   # buckets + chunk shapes (+ draft);
                 #                        no-op for a decode-role engine
@@ -373,9 +386,9 @@ class ExecutableRegistry:
             step = make_serve_step(cfg)
             fn = jax.jit(step, donate_argnums=1)
 
-            def make_inputs(key):
+            def make_inputs(key, telemetry=None):
                 from repro.models.api import init_decode_state
-                params = bundle.init(key)
+                params = weights(key, telemetry)
                 state = init_decode_state(cfg, shape.global_batch,
                                           shape.seq_len)
                 return params, state

@@ -76,7 +76,7 @@ def run_wrapper(arena: SharedArena, proctable: ProcessTable, exe, spec: dict):
             exitcode = _train_loop(exe, key, n_steps, entry, proctable,
                                    telemetry, spec, arena, cap)
         elif exe.image.mode == "prefill":
-            params, batch = exe.make_inputs(key)
+            params, batch = exe.make_inputs(key, telemetry)
             t0 = time.monotonic()
             logits, cache = exe.fn(params, batch)
             jax.block_until_ready(logits)
@@ -90,7 +90,7 @@ def run_wrapper(arena: SharedArena, proctable: ProcessTable, exe, spec: dict):
             exitcode = _serve_loop(exe, key, n_steps, entry, proctable,
                                    telemetry, spec)
         else:                                           # decode
-            params, state = exe.make_inputs(key)
+            params, state = exe.make_inputs(key, telemetry)
             for i in range(n_steps):
                 if entry.stop.is_set():
                     exitcode = 143                      # SIGTERM-by-pilot
@@ -136,7 +136,7 @@ def _serve_loop(exe, key, n_steps, entry, proctable, telemetry, spec) -> int:
     """
     task_id = spec.get("task_id")
     with TraceAnnotation("repro.payload.weights", task_id=task_id):
-        params = exe.make_inputs(key)
+        params = exe.make_inputs(key, telemetry)
     kv_kw = {k: spec[k] for k in ("kv", "prefill", "prefill_chunk",
                                   "num_blocks", "block_size",
                                   "prefix_sharing", "spec", "spec_k",

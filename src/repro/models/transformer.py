@@ -14,6 +14,7 @@ scan as per-iteration xs/ys.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -114,21 +115,30 @@ def _init_slot(key, cfg, slot):
     return p
 
 
-def init_lm_params(cfg, key):
-    """Full parameter pytree; layer leaves have leading dim n_groups."""
+@functools.partial(jax.jit, static_argnums=0)
+def _init_layers(cfg, layer_keys):
+    """The stacked layer leaves, made one group at a time: a staged init
+    holds one group's random bits at once, where a ``vmap`` over the groups
+    holds every layer's.  Jitted on ``cfg``: run eagerly, ``lax.map``
+    would trace and compile its loop on every call."""
     period = group_period(cfg)
-    n_groups = cfg.num_layers // period
     slots = layer_slots(cfg)
-    k_embed, k_head, k_norm, k_layers = jax.random.split(key, 4)
-    layer_keys = jax.random.split(k_layers, (n_groups, period))
 
     def init_group(gkeys):
         return [_init_slot(gkeys[i], cfg, slots[i]) for i in range(period)]
 
-    layers = jax.vmap(init_group)(layer_keys)
+    return jax.lax.map(init_group, layer_keys)
+
+
+def init_lm_params(cfg, key):
+    """Full parameter pytree; layer leaves have leading dim n_groups."""
+    period = group_period(cfg)
+    n_groups = cfg.num_layers // period
+    k_embed, k_head, k_norm, k_layers = jax.random.split(key, 4)
+    layer_keys = jax.random.split(k_layers, (n_groups, period))
     params = {
         "embed": embed_init(k_embed, (cfg.vocab_size, cfg.d_model)),
-        "layers": layers,
+        "layers": _init_layers(cfg, layer_keys),
         "final_norm": init_norm(k_norm, cfg),
     }
     if not cfg.tie_embeddings:

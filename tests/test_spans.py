@@ -149,6 +149,10 @@ def test_second_bind_of_a_bound_image_compiles_nothing(rebind):
     req = rebind["reqs"][2]
     assert len(tel["tokens"][str(req["rid"])]) == req["max_new_tokens"] + 1
     assert rebind["compiles"] == 0
+    # the staged weights init compiled in each image's first bind (no
+    # warm() on this path), and not again
+    assert [t["weights_compiles"] for _, t in rebind["tasks"]] == [1, 1]
+    assert tel["weights_compiles"] == 0
 
 
 def test_spec_draft_is_a_span_and_no_host_sync(tmp_path, monkeypatch):
